@@ -51,6 +51,7 @@ import time
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core import builder
 from repro.data.synthetic import (exact_ground_truth, make_clustered,
@@ -242,4 +243,5 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -48,6 +48,7 @@ import tempfile
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.data.synthetic import make_clustered
 from repro.core.builder import build_scalegann
@@ -292,6 +293,7 @@ def main(smoke: bool = False, trace_out: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI profile: smaller fixture, fewer queries")

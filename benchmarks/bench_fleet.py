@@ -39,6 +39,7 @@ import pathlib
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core import cost_model
 from repro.core.builder import build_scalegann
@@ -259,6 +260,7 @@ def main(smoke: bool = False, trace_out: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI profile: fewer queries, smaller simulation")

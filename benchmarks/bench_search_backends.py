@@ -27,6 +27,7 @@ import json
 import pathlib
 import time
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core import builder
 from repro.data.synthetic import make_clustered, recall_at
@@ -236,4 +237,5 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -42,6 +42,7 @@ import time
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core import builder
 from repro.data.synthetic import make_clustered
@@ -287,6 +288,7 @@ def main(smoke: bool = False, trace_out: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run: jax+pallas, one rate, short trials, "

@@ -7,6 +7,7 @@
    extension: the overhead is measured, not assumed zero).
 """
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core import cost_model
 from repro.core.scheduler import (RuntimeModel, Scheduler, V100_SPOT,
                                   Instance, InstanceType, make_spot_pool,
@@ -49,4 +50,5 @@ def main() -> Rows:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -25,6 +25,7 @@ from __future__ import annotations
 import json
 import os
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import SHAPES, get_arch
 
 from benchmarks.common import Rows
@@ -181,4 +182,5 @@ def main() -> Rows:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

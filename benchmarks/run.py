@@ -14,6 +14,8 @@ import sys
 import time
 import traceback
 
+from repro.common.compile_cache import enable_compile_cache
+
 
 def main() -> int:
     ap = argparse.ArgumentParser()
@@ -60,4 +62,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -4,6 +4,7 @@ Claims validated: shard index build dominates partition + merge, and its
 share grows with (R, L).
 """
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core.builder import build_diskann
 
@@ -33,4 +34,5 @@ def main() -> Rows:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

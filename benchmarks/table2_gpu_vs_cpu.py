@@ -9,6 +9,7 @@ measures (matmul-offloadable brute-force kNN vs pointer-chasing greedy
 search).
 """
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core.cagra import build_shard_index
 # the *sequential* build: Table II's CPU side must stay pointer-chasing
@@ -38,4 +39,5 @@ def main() -> Rows:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

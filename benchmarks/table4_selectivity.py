@@ -7,6 +7,7 @@ budget / distance computations at fixed recall) is maintained or improved.
 
 import dataclasses
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core.builder import build_scalegann
 from repro.data.synthetic import recall_at
@@ -61,4 +62,5 @@ def main() -> Rows:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -7,6 +7,7 @@ split-only at equal recall; ScaleGANN build-only ≤ 2× Extended CAGRA;
 DiskANN (CPU) is the slowest builder.
 """
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core import builder
 from repro.data.synthetic import recall_at
@@ -71,4 +72,5 @@ def main() -> Rows:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

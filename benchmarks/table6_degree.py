@@ -8,6 +8,7 @@ share rises).
 
 import dataclasses
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core import builder
 
@@ -36,4 +37,5 @@ def main() -> Rows:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

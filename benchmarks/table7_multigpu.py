@@ -8,6 +8,7 @@ Two measurements:
      host-core contention.
 """
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core.builder import build_scalegann
 from repro.core.scheduler import (RuntimeModel, Scheduler,
@@ -43,4 +44,5 @@ def main() -> Rows:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,7 @@ under both scheduling policies for comparison.
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core.scheduler import (SCHEDULING_POLICIES, Scheduler,
                                   calibrate_runtime, make_spot_pool,
@@ -104,4 +105,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig, get_arch, smoke_config
 from repro.core.builder import build_scalegann
 from repro.data.pipeline import TokenPipeline, TokenPipelineConfig
@@ -68,4 +69,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core.builder import build_scalegann
 from repro.core.merge import connectivity_stats
@@ -292,4 +293,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -14,6 +14,7 @@ import time
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import IndexConfig
 from repro.core.builder import build_scalegann
 from repro.data.synthetic import make_clustered, recall_at
@@ -70,4 +71,5 @@ async def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     asyncio.run(main())

@@ -8,6 +8,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import get_arch, smoke_config
 from repro.models.model import build_model
 from repro.serve.engine import Request, ServeConfig, ServeEngine
@@ -44,4 +45,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

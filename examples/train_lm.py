@@ -15,6 +15,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import get_arch
 from repro.data.pipeline import TokenPipeline, TokenPipelineConfig
 from repro.models.model import build_model
@@ -88,4 +89,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -37,6 +37,17 @@ class ShardIndex:
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames=("L",))
+def _drop_self(d: jax.Array, i: jax.Array, start: jax.Array, L: int):
+    """Mask each row's self-match (row r of the block is vector
+    ``start + r``) and keep the L nearest, ascending."""
+    rows = start + jnp.arange(d.shape[0], dtype=jnp.int32)[:, None]
+    d = jnp.where(i == rows, jnp.inf, d)
+    order = jnp.argsort(d, axis=1)[:, :L]
+    return (jnp.take_along_axis(d, order, axis=1),
+            jnp.take_along_axis(i, order, axis=1))
+
+
 def build_knn_graph(
     vectors: np.ndarray, L: int, *, metric: str = "l2", row_block: int = 4096
 ) -> tuple[np.ndarray, np.ndarray, int]:
@@ -50,16 +61,19 @@ def build_knn_graph(
     x = jnp.asarray(vectors, jnp.float32)
     n = x.shape[0]
     k = min(L + 1, n)  # +1: the self-match is removed below
+    rb = min(row_block, n)
     nbrs, dists = [], []
-    for s in range(0, n, row_block):
-        q = x[s : s + row_block]
-        d, i = ops.knn(q, x, k, metric)
-        rows = jnp.arange(s, s + q.shape[0])[:, None]
-        self_mask = i == rows
-        d = jnp.where(self_mask, jnp.inf, d)
-        order = jnp.argsort(d, axis=1)[:, : L]
-        nbrs.append(np.asarray(jnp.take_along_axis(i, order, axis=1)))
-        dists.append(np.asarray(jnp.take_along_axis(d, order, axis=1)))
+    for s in range(0, n, rb):
+        # every block has rb query rows (the last one padded with copies
+        # of row 0, dropped below): one compiled shape per shard size
+        q = x[s : s + rb]
+        real = q.shape[0]
+        if real < rb:
+            q = jnp.concatenate([q, jnp.broadcast_to(x[:1], (rb - real,
+                                                              x.shape[1]))])
+        d, i = _drop_self(*ops.knn(q, x, k, metric), jnp.int32(s), L)
+        nbrs.append(np.asarray(i)[:real])
+        dists.append(np.asarray(d)[:real])
     nbrs = np.concatenate(nbrs)
     dists = np.concatenate(dists)
     if n <= L:  # degenerate tiny shard: pad
@@ -94,7 +108,7 @@ def _detour_counts(vs: jax.Array, nbr_vecs: jax.Array, nbr_dists: jax.Array,
     rank_lt = jnp.arange(L)[:, None] < jnp.arange(L)[None, :]  # i < j
     detour = (d_ij < nbr_dists[:, None, :]) & rank_lt[None]
     valid = jnp.isfinite(nbr_dists)
-    return jnp.sum(detour, axis=1) + jnp.where(valid, 0, 10**6), d_ij.shape[0] * L * L
+    return jnp.sum(detour, axis=1) + jnp.where(valid, 0, 10**6)
 
 
 def _fill_reverse_loop(
@@ -211,16 +225,20 @@ def optimize_graph(
     n_dist = 0
     counts = np.empty((n, L), np.int64)
     safe_nbrs = np.maximum(nbrs, 0)
-    for s in range(0, n, node_block):
-        e = min(s + node_block, n)
-        c, nd = _detour_counts(
-            jnp.asarray(x[s:e]),
-            jnp.asarray(x[safe_nbrs[s:e]]),
-            jnp.asarray(dists[s:e]),
+    nb = min(node_block, n)
+    for s in range(0, n, nb):
+        e = min(s + nb, n)
+        # fixed block shape (the last block repeats row n-1, dropped
+        # below): one compile per shard size, not one per remainder
+        rows = np.minimum(np.arange(s, s + nb), n - 1)
+        c = _detour_counts(
+            jnp.asarray(x[rows]),
+            jnp.asarray(x[safe_nbrs[rows]]),
+            jnp.asarray(dists[rows]),
             metric,
         )
-        counts[s:e] = np.asarray(c)
-        n_dist += int(nd)
+        counts[s:e] = np.asarray(c)[: e - s]
+        n_dist += (e - s) * L * L
     # stable: prefer fewer detours, break ties by distance rank (ascending)
     order = np.argsort(counts, axis=1, kind="stable")
     fwd = np.take_along_axis(nbrs, order[:, :fwd_keep], axis=1)  # [n, R/2]

@@ -29,6 +29,32 @@ from repro.core.cagra import ShardIndex
 from repro.core.partition import Shard
 
 
+def entry_points(center: int, n_vectors: int, n: int = 16) -> np.ndarray:
+    """``center`` + ``n`` rows spread evenly over ``[0, n_vectors)``, as
+    sorted unique row ids.
+
+    Always exactly ``min(n + 1, n_vectors)`` seeds: the center regularly
+    collides with one of the ``linspace`` samples, and before the
+    deterministic top-up below a collision silently shrank the seed set —
+    replicas agreed with each other but not with the documented contract,
+    and searches seeded one entry short.  A fixed count also keeps the seed
+    array one shape for the jitted beams."""
+    want = min(n + 1, n_vectors)
+    seeds = np.unique(np.concatenate(
+        [[center], np.linspace(0, n_vectors - 1, n, dtype=np.int64)]
+    ))
+    if len(seeds) < want:
+        # top up with the smallest ids not already chosen — ids in
+        # [0, want + len(seeds)) suffice by pigeonhole, so the scan
+        # stays O(n), not O(n_vectors)
+        fresh = np.setdiff1d(
+            np.arange(min(want + len(seeds), n_vectors), dtype=np.int64),
+            seeds, assume_unique=True,
+        )
+        seeds = np.unique(np.concatenate([seeds, fresh[: want - len(seeds)]]))
+    return seeds
+
+
 @dataclasses.dataclass
 class GlobalIndex:
     """Merged graph over the full dataset, global coordinates, -1 padded."""
@@ -40,31 +66,9 @@ class GlobalIndex:
     def entry_points(self, n: int = 16) -> np.ndarray:
         """Medoid + a stratified sample — CAGRA-style multi-entry seeds (a
         merged kNN graph has only local edges; multiple entries restore
-        navigability; deterministic so serving replicas agree).
-
-        Always exactly ``min(n + 1, n_vectors)`` unique seeds: the medoid
-        regularly collides with one of the ``linspace`` samples, and before
-        the deterministic top-up below a collision silently shrank the seed
-        set — replicas agreed with each other but not with the documented
-        contract, and searches seeded one entry short."""
-        want = min(n + 1, self.n_vectors)
-        seeds = np.unique(np.concatenate(
-            [[self.medoid], np.linspace(0, self.n_vectors - 1, n,
-                                        dtype=np.int64)]
-        ))
-        if len(seeds) < want:
-            # top up with the smallest ids not already chosen — ids in
-            # [0, want + len(seeds)) suffice by pigeonhole, so the scan
-            # stays O(n), not O(n_vectors)
-            fresh = np.setdiff1d(
-                np.arange(min(want + len(seeds), self.n_vectors),
-                          dtype=np.int64), seeds,
-                assume_unique=True,
-            )
-            seeds = np.unique(np.concatenate(
-                [seeds, fresh[: want - len(seeds)]]
-            ))
-        return seeds
+        navigability; deterministic so serving replicas agree).  See
+        :func:`entry_points`."""
+        return entry_points(self.medoid, self.n_vectors, n)
 
     @property
     def degree(self) -> int:
@@ -174,18 +178,23 @@ def _union_dedup_cap(
     if gids.size == 0:
         return graph
     # dedup: stable (gid, nbr) sort keeps the earliest appended copy first
-    order = np.lexsort((nbrs, gids))
+    # (one int64 key sorts like lexsort((nbrs, gids)), in half the time)
+    order = np.argsort(gids * n_total + nbrs, kind="stable")
     sg, sn = gids[order], nbrs[order]
     first = np.ones(len(sg), bool)
     first[1:] = (sg[1:] != sg[:-1]) | (sn[1:] != sn[:-1])
     ug, un, upos = sg[first], sn[first], order[first]
     # cap: order each gid's unique neighbors by (distance, first-seen) or
-    # (first-seen) alone, then keep ranks < degree
+    # (first-seen) alone, then keep ranks < degree.  A stable sort of the
+    # first-seen order by an int64 (gid, distance) key sorts like
+    # lexsort((upos, d, ug)): non-negative f32 bit patterns order as values.
+    by_pos = np.argsort(upos)
     if data is not None:
         d = _segment_distances(data, ug, un)
-        sel = np.lexsort((upos, d, ug))
+        key = (ug << 32) | d.view(np.int32).astype(np.int64)
     else:
-        sel = np.lexsort((upos, ug))
+        key = ug
+    sel = by_pos[np.argsort(key[by_pos], kind="stable")]
     g2, n2 = ug[sel], un[sel]
     idx = np.arange(len(sel))
     seg_start = np.ones(len(sel), bool)

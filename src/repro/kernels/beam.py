@@ -16,17 +16,22 @@ device-resident engine — the BANG/PilotANN layout on TPU terms:
     kernel body runs, so seeding reads scalars instead of streaming a
     block, and the graph/vector blocks for the first hop are already being
     fetched while the seeds score.
-  * **One dense MXU tile per query, then pure on-chip traversal.**  The
-    prologue computes the query's distance-score vector against the whole
-    resident shard (f32/bf16: one ``[1, D]×[D, N]`` matmul; uint8: the
+  * **One score row per query, then pure on-chip traversal.**  The
+    query's score row over the whole resident shard comes first (f32/bf16:
+    one ``[Q, D]×[D, N]`` XLA matmul per batch, the same op the XLA
+    lowering precomputes, each program reading its row; uint8: the
     **int8-native MXU** path of :func:`repro.kernels.distance._u8_code_dots`
-    — codes recentered into int8, int8×int8→int32 ``dot_general``).  Every
-    per-trip neighbor score is then a VMEM gather, done as a one-hot
-    matmul (Mosaic has no vector gather) with an exact 16-bit hi/lo split
-    for int32 payloads.  This trades O(N·D) MXU work per query for a
-    traversal that never leaves VMEM — the right trade for shard-resident
-    panels (N·D ≤ ~4M elements in 16 MB VMEM); larger shards would stream
-    x panels per wavefront behind the same prefetch channel.
+    in the kernel — codes recentered into int8, int8×int8→int32
+    ``dot_general``).  Every per-trip neighbor score is then a VMEM gather,
+    done as a one-hot bf16 matmul (Mosaic has no vector gather) over
+    ``[expand·R, COLUMN_CHUNK]`` column tiles, exact through a bf16 split
+    of the gathered values (:func:`_gather_exact`).  Resident in VMEM are
+    the graph, the ``[1, N]`` rows (norms, visited tags, score rows) and,
+    for uint8, the codes and the exact re-rank rows; compiled for v5e at
+    D = 128, R = 64 that is up to 184,319 vectors for f32/bf16 and 86,015
+    for uint8 with re-rank (:func:`pallas_vmem_bytes` against
+    :data:`VMEM_LIMIT_BYTES`).  A larger shard raises; streaming it from
+    HBM is future work.
   * **Fused exact re-rank epilogue.**  For staged dtypes the kernel ends
     by re-scoring its top ``kq`` candidates against the resident f32
     vectors and sorting by ``(distance, id)`` — a served batch never
@@ -68,12 +73,63 @@ DEFAULT_EXPAND = 8
 # (one sgemm per batch, per-trip scoring becomes pure gathers) when the tile
 # stays under this many elements; bigger panels score gathered rows per trip
 PRECOMPUTE_TILE_LIMIT = 4 * 1024 * 1024
+# shard columns per one-hot tile in the Pallas kernel's per-trip gathers
+COLUMN_CHUNK = 2048
+
+# scoped-VMEM limit the Pallas kernel compiles under (v5e has 128 MiB of
+# VMEM; the compiler's default scope is 16 MiB).  It is also the bound
+# :func:`pallas_vmem_bytes` is held to.  Compiled for a v5e chip at
+# D = 128, R = 64, the model stays above the compiler's scoped allocation
+# (532-551 bytes a column for f32 near the limit): the f32 stage compiles
+# at N = 184319 (the model's largest) and is refused at N = 192511
+# (106.0 MB); uint8 with re-rank compiles at N = 86015 (the model's
+# largest) and at 88063, and is refused at N = 98304.
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
+# f32 dots run at full precision in the XLA lowering and in the Pallas
+# wrapper's batch matmul: a TPU's default f32 matmul rounds operands to
+# bf16, which would make the "exact" f32 stage approximate
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _round_up(v: int, m: int) -> int:
     return -(-v // m) * m
+
+
+def _columns(n: int) -> tuple[int, int]:
+    """(chunk, Np): one-hot tile width and the padded column count (N + 1
+    with the spill column, a multiple of the chunk)."""
+    chunk = min(COLUMN_CHUNK, _round_up(n + 1, LANE))
+    return chunk, _round_up(n + 1, chunk)
+
+
+def pallas_vmem_bytes(n: int, d: int, r: int, x_dtype, *,
+                      dx: int | None = None) -> int:
+    """VMEM the Pallas lowering holds for an ``[n, d]`` shard of degree
+    ``r``: the lane-padded graph, the uint8 code panel (the float stages
+    read a precomputed score row instead), the exact re-rank rows when
+    ``dx`` is given, and 40 bytes a column for the ``[1, Np]`` rows (norms,
+    code sums and the query's score row, double-buffered; visited tags and
+    two score rows; rounded up), plus 2 MiB for what does not grow with
+    ``n``: the ``[expand·r, chunk]`` one-hot tiles and the candidate
+    state."""
+    _, np_cols = _columns(n)
+    row = _round_up(r, LANE) * 4 + 40
+    if jnp.dtype(x_dtype) == jnp.uint8:
+        row += _round_up(d, LANE)
+    if dx is not None:
+        row += _round_up(dx, LANE) * 4
+    return np_cols * row + (2 << 20)
+
+
+def _pad_lanes(a: jax.Array, width: int, value) -> jax.Array:
+    """Right-pad a ``[1, w]`` row to ``width`` lanes with ``value`` (no
+    zero-width piece: Mosaic refuses empty vectors)."""
+    if a.shape[1] == width:
+        return a
+    return jnp.concatenate(
+        [a, jnp.full((1, width - a.shape[1]), value, a.dtype)], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -155,7 +211,8 @@ def _fused_beam_xla(
             # one sgemm per batch; traversal scoring becomes pure gathers.
             # Same reduction as the gathered-rows dot, so bit-identical.
             wall = jnp.concatenate(
-                [qf @ xf.T, jnp.zeros((nq, 1), jnp.float32)], axis=1
+                [jnp.matmul(qf, xf.T, precision=_EXACT),
+                 jnp.zeros((nq, 1), jnp.float32)], axis=1
             ).reshape(-1)  # [Q·(N+1)] flat, spill column N
             xn1 = jnp.concatenate([xn, jnp.zeros((1,), jnp.float32)])
 
@@ -174,7 +231,7 @@ def _fused_beam_xla(
                 rows = xf[safe.reshape(-1)].reshape(nq, m, d_real)
                 dots = jax.lax.dot_general(
                     qf, rows, (((1,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
+                    preferred_element_type=jnp.float32, precision=_EXACT,
                 )
                 if metric == "ip":
                     return -dots
@@ -280,7 +337,7 @@ def _fused_beam_xla(
         nq, k, -1
     )
     if metric == "ip":
-        dex = -jnp.einsum("qcd,qd->qc", rows, q_exact)
+        dex = -jnp.einsum("qcd,qd->qc", rows, q_exact, precision=_EXACT)
     else:
         diff = rows - q_exact[:, None, :]
         dex = jnp.sum(diff * diff, axis=-1)
@@ -299,10 +356,31 @@ def _fused_beam_xla(
 # ---------------------------------------------------------------------------
 
 
+def _gather_exact(vals, eq, pieces: int):
+    """``[1, Np]`` f32 values through an ``[M, Np]`` bf16 one-hot to
+    ``[1, M]``, exactly.  Each row of ``eq`` holds one 1, so a single-pass bf16 matmul
+    returns the selected bf16 value unchanged; ``vals`` is split into
+    ``pieces`` bf16 parts that sum back to it in f32 (3 cover a 24-bit
+    significand, 2 an integer below 2**16) and each part is gathered in one
+    pass.  This keeps the one-hot operand bf16: a full-precision f32
+    matmul would split it too, and spills out of VMEM at shard size."""
+    out, rest = None, vals
+    for _ in range(pieces):
+        part = rest.astype(jnp.bfloat16)
+        rest = rest - part.astype(jnp.float32)
+        g = jax.lax.dot_general(
+            part, eq, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        out = g if out is None else out + g
+    return out
+
+
 def _beam_kernel(
     ent_ref,  # [E] int32 SMEM (scalar-prefetch operand)
     q_ref,  # [1, D] query block (f32 / bf16 / uint8 codes)
-    x_ref,  # [Np(-1), D] resident storage
+    w_ref,  # [1, Np] f32 query·shard dots (f32/bf16 stages; dummy for u8)
+    x_ref,  # [Np(-1), D] uint8 codes (u8 stage; dummy otherwise)
     graph_ref,  # [Np(-1), R] int32
     xaux1_ref,  # [1, Np] f32 norms | int32 code norms
     xaux2_ref,  # [1, Np] int32 code sums (uint8 ip; zeros otherwise)
@@ -319,9 +397,11 @@ def _beam_kernel(
     cd_ref,  # VMEM scratch [1, W] f32 — candidate distances
     ci_ref,  # VMEM scratch [1, W] int32 — candidate ids
     ce_ref,  # VMEM scratch [1, W] int32 — expanded flags
+    sc_ref,  # VMEM scratch [2, Np] f32 — the query's score row(s)
     *,
     n: int,  # real point count (sentinel id)
-    np_cols: int,  # padded N+1 (lane multiple)
+    np_cols: int,  # padded N+1 (chunk multiple)
+    chunk: int,  # columns per one-hot tile
     d_real: int,
     n_entries: int,
     k: int,
@@ -336,60 +416,67 @@ def _beam_kernel(
     n_new = expand * r
     sentinel = jnp.int32(n)
     iota_w = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
-    iota_np = jax.lax.broadcasted_iota(jnp.int32, (1, np_cols), 1)
+    n_chunks = np_cols // chunk
 
-    # ---- prologue: the query's dense score vector over the shard ----
+    def over_chunks(fn, init):
+        """``fn(lanes, ids, carry)`` over the shard's column chunks: work
+        that touches every column (one-hot gathers, visited tags) runs on
+        ``[M, chunk]`` tiles, so VMEM and code size do not grow with N."""
+        def step(c, carry):
+            off = pl.multiple_of(c * chunk, chunk)
+            ids = off + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+            return fn(pl.ds(off, chunk), ids, carry)
+        return jax.lax.fori_loop(0, n_chunks, step, init)
+
+    # ---- prologue: the query's dense score row over the shard, staged in
+    # sc_ref (f32 scores, or the int32 L2 code scores split lo/hi 16/16 so
+    # the one-hot gathers stay integer-exact) ----
     if stage == "u8":
-        dots, sq, _sx = _u8_code_dots(q_ref[...], x_ref[...])  # [1, Np]
         s = s_ref[0, 0]
         zp = zp_ref[0, 0]
         qi = q_ref[...].astype(jnp.int32)
         cqn = jnp.sum(qi * qi)  # scalar query-code norm
-        if metric == "ip":
-            sc_f = -(s * s * dots.astype(jnp.float32)
-                     + s * zp * (sq[0, 0] + xaux2_ref[...]).astype(
-                         jnp.float32)
-                     + d_real * zp * zp)  # [1, Np] absolute ip scores
-            sc_hi = sc_lo = None
-        else:
-            # exact int32 ranking scores; converted after the gather so
-            # the hi/lo one-hot split stays integer-exact
-            sci = xaux1_ref[...] + cqn - 2 * dots  # [1, Np] int32
-            sc_lo = (sci & 0xFFFF).astype(jnp.float32)
-            sc_hi = (sci >> 16).astype(jnp.float32)
-            sc_f = None
+        cqs = jnp.sum(qi)  # scalar query-code sum
+
+        def prologue(lanes, _ids, carry):
+            dots, _, _ = _u8_code_dots(q_ref[...], x_ref[lanes, :])
+            if metric == "ip":  # absolute ip scores
+                sc_ref[0:1, lanes] = -(
+                    s * s * dots.astype(jnp.float32)
+                    + s * zp * (cqs + xaux2_ref[:, lanes]).astype(
+                        jnp.float32)
+                    + d_real * zp * zp)
+            else:
+                sci = xaux1_ref[:, lanes] + cqn - 2 * dots  # [1, chunk]
+                sc_ref[0:1, lanes] = (sci & 0xFFFF).astype(jnp.float32)
+                sc_ref[1:2, lanes] = (sci >> 16).astype(jnp.float32)
+            return carry
     else:
-        qv = q_ref[...].astype(jnp.float32)  # [1, D]
-        xf = x_ref[...].astype(jnp.float32)  # [Np, D]
-        w = jax.lax.dot_general(
-            qv, xf, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [1, Np] — the one MXU tile; traversal only gathers from it
-        if metric == "ip":
-            sc_f = -w
-        else:
-            sc_f = xaux1_ref[...] - 2.0 * w  # ‖x‖² − 2·q·x
-        sc_hi = sc_lo = None
         s = zp = None
 
-    def gather_scores(ids_col):
-        """[M, 1] ids → [1, M] score values via one-hot matmul (exact:
-        one non-zero per row; int32 payloads split 16/16)."""
-        eq = (ids_col == iota_np).astype(jnp.float32)  # [M, Np]
-        if sc_f is not None:
-            return jax.lax.dot_general(
-                sc_f, eq, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [1, M]
-        lo = jax.lax.dot_general(
-            sc_lo, eq, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.int32)
-        hi = jax.lax.dot_general(
-            sc_hi, eq, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.int32)
-        sci = hi * 65536 + lo  # exact int32 ranking score
+        def prologue(lanes, _ids, carry):
+            w = w_ref[:, lanes]  # query·x from the batch matmul
+            if metric == "ip":
+                sc_ref[0:1, lanes] = -w
+            else:
+                sc_ref[0:1, lanes] = xaux1_ref[:, lanes] - 2.0 * w
+            return carry
+    over_chunks(prologue, 0)
+    split = stage == "u8" and metric != "ip"
+
+    def gather_part(lanes, eq, acc):
+        """Add one chunk's one-hot gather of the score row to ``acc``
+        (exact: a row's one non-zero sits in exactly one chunk)."""
+        if not split:
+            return acc + _gather_exact(sc_ref[0:1, lanes], eq, 3)
+        lo = _gather_exact(sc_ref[0:1, lanes], eq, 2)
+        hi = _gather_exact(sc_ref[1:2, lanes], eq, 2)
+        return acc + jnp.concatenate([lo, hi], axis=0)
+
+    def finish_scores(acc):
+        if not split:
+            return acc  # [1, M]
+        sci = acc[1:2].astype(jnp.int32) * 65536 + acc[0:1].astype(jnp.int32)
         return jnp.maximum(sci.astype(jnp.float32), 0.0) * (s * s)
 
     # ---- seeding from the scalar-prefetch entries ----
@@ -403,16 +490,17 @@ def _beam_kernel(
     # one-hot per candidate slot against its id; padding slots gather the
     # spill column and are masked to inf below
     id_col = jnp.where(seed_col < n_entries, jnp.transpose(ci0), sentinel)
-    seed_d = gather_scores(id_col)  # [1, width]
-    cd0 = jnp.where(iota_w < n_entries, seed_d, jnp.inf)
-    tags0 = jnp.where(
-        jnp.sum((id_col == iota_np).astype(jnp.int32)
-                * jnp.where(seed_col < n_entries, 1, 0),
-                axis=0, keepdims=True) > 0,
-        1, 0,
-    ).astype(jnp.int32)  # visited tags: seeds = 1
-    tags_ref[...] = tags0
-    cd_ref[...] = cd0
+    n_acc = 2 if split else 1
+
+    def seed(lanes, ids, acc):
+        hit = id_col == ids  # [width, chunk]
+        tags_ref[:, lanes] = (jnp.sum(
+            jnp.where(hit & (seed_col < n_entries), 1, 0),
+            axis=0, keepdims=True) > 0).astype(jnp.int32)  # seeds = 1
+        return gather_part(lanes, hit.astype(jnp.bfloat16), acc)
+    seed_d = finish_scores(
+        over_chunks(seed, jnp.zeros((n_acc, width), jnp.float32)))
+    cd_ref[...] = jnp.where(iota_w < n_entries, seed_d, jnp.inf)
     ci_ref[...] = ci0
     ce_ref[...] = ce0
 
@@ -446,24 +534,27 @@ def _beam_kernel(
         # gather the wavefront's graph rows (scalar dynamic row slices —
         # the ids were just computed, so these are the VMEM-resident
         # equivalent of the prefetch-stream for larger-than-VMEM graphs)
-        rows = [
-            pl.load(graph_ref,
-                    (pl.ds(jnp.clip(v, 0, n - 1), 1), slice(None)))
-            for v in vs
-        ]
-        nbrs = jnp.concatenate(rows, axis=0).reshape(1, n_new)
-        live_row = jnp.concatenate(
-            [jnp.full((1, r), lv, bool) for lv in lives], axis=1
-        ).reshape(1, n_new)
-        valid = (nbrs >= 0) & live_row & jnp.logical_not(halt)
+        rows = [graph_ref[pl.ds(jnp.clip(v, 0, n - 1), 1), :] for v in vs]
+        nbrs = jnp.concatenate(rows, axis=1)  # [1, n_new]
+        # lane j of the wavefront belongs to selection j // r; masks are
+        # built from int32 (Mosaic cannot concatenate or broadcast masks)
+        blk = iota_nn_r // r
+        live_i = jnp.zeros((1, n_new), jnp.int32)
+        for t, lv in enumerate(lives):
+            live_i = jnp.where(blk == t, lv.astype(jnp.int32), live_i)
+        live_i = live_i * (1 - halt.astype(jnp.int32))
+        valid = (nbrs >= 0) & (live_i != 0)
         safe_r = jnp.where(valid, nbrs, sentinel)  # [1, n_new]
         safe_c = jnp.transpose(safe_r)  # [n_new, 1]
-        eq = (safe_c == iota_np).astype(jnp.float32)  # [n_new, Np]
-        tags_f = tags_ref[...].astype(jnp.float32)  # tags < 2^24: exact
-        seen_r = jax.lax.dot_general(
-            tags_f, eq, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) != 0.0  # [1, n_new]
+
+        def seen_part(lanes, ids, acc):
+            eq = (safe_c == ids).astype(jnp.bfloat16)  # [n_new, chunk]
+            visited = (tags_ref[:, lanes] != 0).astype(jnp.bfloat16)
+            return acc + jax.lax.dot_general(
+                visited, eq, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # 0/1 sums: exact
+        seen_r = over_chunks(
+            seen_part, jnp.zeros((1, n_new), jnp.float32)) != 0.0
         cand = valid & ~seen_r
         # within-wavefront duplicate ids resolve to the *last* occurrence
         # (the jax backend's scatter semantics): drop j if a later k
@@ -473,33 +564,35 @@ def _beam_kernel(
         dup_later = jnp.sum(
             (eqp & later & cand).astype(jnp.int32), axis=1, keepdims=True
         ) > 0  # [n_new, 1] — j has a later duplicate candidate
-        fresh_c = jnp.transpose(cand) & ~dup_later  # [n_new, 1]
-        fresh_r = jnp.transpose(fresh_c)
-        # visited-tag scratch update: winners write their unique slot
+        # transposes move int32, never masks (Mosaic transposes 32-bit)
+        fresh_c = (jnp.transpose(cand.astype(jnp.int32)) != 0) & ~dup_later
+        fresh_r = jnp.transpose(fresh_c.astype(jnp.int32)) != 0  # [1, n_new]
         slot_c = 2 + it * n_new + iota_nn_c  # [n_new, 1] int32
-        contrib = jnp.where(
-            (safe_c == iota_np) & fresh_c, slot_c, 0
-        )  # [n_new, Np]
-        maxslot = jnp.max(contrib, axis=0, keepdims=True)  # [1, Np]
-        tags_ref[...] = jnp.where(maxslot > 0, maxslot, tags_ref[...])
-        nd_row = jnp.where(fresh_r, gather_scores(safe_c), jnp.inf)
+
+        def update_part(lanes, ids, acc):
+            """Visited-tag update (winners write their unique slot) and
+            the fresh neighbors' score gather, one chunk at a time."""
+            hit = safe_c == ids  # [n_new, chunk]
+            maxslot = jnp.max(jnp.where(hit & fresh_c, slot_c, 0), axis=0,
+                              keepdims=True)  # [1, chunk]
+            tags_ref[:, lanes] = jnp.where(maxslot > 0, maxslot,
+                                           tags_ref[:, lanes])
+            return gather_part(lanes, hit.astype(jnp.bfloat16), acc)
+        nd = finish_scores(over_chunks(
+            update_part, jnp.zeros((n_acc, n_new), jnp.float32)))
+        nd_row = jnp.where(fresh_r, nd, jnp.inf)
         # bounded beam: keep the best `width` of (candidates ∪ fresh) by
         # (distance, position) — the bitonic network IS lax.top_k here
         total = width + n_new
         p2 = _next_pow2(total)
-        all_d = jnp.concatenate(
-            [cd, nd_row,
-             jnp.full((1, p2 - total), jnp.inf, jnp.float32)], axis=1
-        )
+        all_d = _pad_lanes(jnp.concatenate([cd, nd_row], axis=1), p2,
+                           jnp.inf)
         all_pos = jax.lax.broadcasted_iota(jnp.int32, (1, p2), 1)
-        all_ids = jnp.concatenate(
-            [ci, jnp.where(fresh_r, nbrs, sentinel),
-             jnp.full((1, p2 - total), sentinel, jnp.int32)], axis=1
-        )
+        all_ids = _pad_lanes(
+            jnp.concatenate([ci, jnp.where(fresh_r, nbrs, sentinel)],
+                            axis=1), p2, sentinel)
         ce_u = jnp.where(selmask, 1, ce)
-        all_exp = jnp.concatenate(
-            [ce_u, jnp.zeros((1, n_new + p2 - total), jnp.int32)], axis=1
-        )
+        all_exp = _pad_lanes(ce_u, p2, 0)
         sd, spos, (sids, sexp) = bitonic_sort_lex(
             all_d, all_pos, (all_ids, all_exp), tie_by_index=True
         )
@@ -524,14 +617,8 @@ def _beam_kernel(
 
     # ---- final top-k: full (distance, position) sort of the list ----
     wp2 = _next_pow2(width)
-    fin_d = jnp.concatenate(
-        [cd_ref[...],
-         jnp.full((1, wp2 - width), jnp.inf, jnp.float32)], axis=1
-    )
-    fin_ids = jnp.concatenate(
-        [ci_ref[...],
-         jnp.full((1, wp2 - width), sentinel, jnp.int32)], axis=1
-    )
+    fin_d = _pad_lanes(cd_ref[...], wp2, jnp.inf)
+    fin_ids = _pad_lanes(ci_ref[...], wp2, sentinel)
     fin_pos = jax.lax.broadcasted_iota(jnp.int32, (1, wp2), 1)
     sd, _, (sids,) = bitonic_sort_lex(
         fin_d, fin_pos, (fin_ids,), tie_by_index=True
@@ -544,13 +631,13 @@ def _beam_kernel(
     if metric != "ip" and stage != "u8":
         qv = q_ref[...].astype(jnp.float32)
         out_d = out_d + jnp.sum(qv * qv)
-    out_nd_ref[0, 0] = n_dist
-    out_hops_ref[0, 0] = hops
+    out_nd_ref[...] = jnp.full((1, 1), n_dist, jnp.int32)
+    out_hops_ref[...] = jnp.full((1, 1), hops, jnp.int32)
 
     if rerank_k is None:
         out_ids_ref[...] = out_ids
         out_d_ref[...] = out_d
-        out_nrr_ref[0, 0] = 0
+        out_nrr_ref[...] = jnp.zeros((1, 1), jnp.int32)
         return
 
     # ---- fused exact-f32 re-rank epilogue (VMEM-resident rows) ----
@@ -559,9 +646,7 @@ def _beam_kernel(
     dex = jnp.zeros((1, k), jnp.float32)
     for j in range(k):
         cid = jnp.sum(jnp.where(iota_k == j, out_ids, 0))
-        row = pl.load(
-            xex_ref, (pl.ds(jnp.clip(cid, 0, n - 1), 1), slice(None))
-        )  # [1, Dx]
+        row = xex_ref[pl.ds(jnp.clip(cid, 0, n - 1), 1), :]  # [1, Dx]
         if metric == "ip":
             dj = -jnp.sum(row * qx)
         else:
@@ -570,19 +655,14 @@ def _beam_kernel(
         dex = jnp.where(iota_k == j, dj, dex)
     valid = out_ids >= 0
     kp2 = _next_pow2(max(k, 2))
-    d_key = jnp.concatenate(
-        [jnp.where(valid, dex, jnp.inf),
-         jnp.full((1, kp2 - k), jnp.inf, jnp.float32)], axis=1
-    )
-    id_key = jnp.concatenate(
-        [jnp.where(valid, out_ids, _I32_MAX),
-         jnp.full((1, kp2 - k), _I32_MAX, jnp.int32)], axis=1
-    )
+    d_key = _pad_lanes(jnp.where(valid, dex, jnp.inf), kp2, jnp.inf)
+    id_key = _pad_lanes(jnp.where(valid, out_ids, _I32_MAX), kp2, _I32_MAX)
     sdex, sidex, _ = bitonic_sort_lex(d_key, id_key, tie_by_index=True)
     r_ids = sidex[:, :rerank_k]
     out_ids_ref[...] = jnp.where(r_ids == _I32_MAX, -1, r_ids)
     out_d_ref[...] = sdex[:, :rerank_k]
-    out_nrr_ref[0, 0] = jnp.sum(valid.astype(jnp.int32))
+    out_nrr_ref[...] = jnp.full((1, 1), jnp.sum(valid.astype(jnp.int32)),
+                                jnp.int32)
 
 
 @functools.partial(
@@ -616,14 +696,16 @@ def _fused_beam_pallas(
     is_u8 = x.dtype == jnp.uint8
     stage = "u8" if is_u8 else (
         "bf16" if x.dtype == jnp.bfloat16 else "f32")
-    np_cols = _round_up(n + 1, LANE)
+    chunk, np_cols = _columns(n)
     d_pad = _round_up(d, LANE)
     # resident panels, padded to the lane grid (zero rows/columns are
-    # exact for both metrics and both stages; see _u8_code_dots)
-    xp = jnp.pad(x, ((0, np_cols - n), (0, d_pad - d)))
+    # exact for both metrics and both stages; see _u8_code_dots).  Operands
+    # a stage does not read are one-tile dummies that keep one signature.
     gp = jnp.pad(graph, ((0, np_cols - n), (0, 0)), constant_values=-1)
     qp = jnp.pad(queries, ((0, 0), (0, d_pad - d)))
     if is_u8:
+        xp = jnp.pad(x, ((0, np_cols - n), (0, d_pad - d)))
+        w = jnp.zeros((nq, 1, LANE), jnp.float32)
         xi = x.astype(jnp.int32)
         aux1 = jnp.pad(
             jnp.sum(xi * xi, axis=1)[None, :], ((0, 0), (0, np_cols - n))
@@ -632,7 +714,14 @@ def _fused_beam_pallas(
             jnp.sum(xi, axis=1)[None, :], ((0, 0), (0, np_cols - n))
         )  # [1, Np] code sums
     else:
+        xp = jnp.zeros((8, LANE), x.dtype)
         xf = x.astype(jnp.float32)
+        # the query·shard dots for the whole batch in one XLA matmul, the
+        # same op as the XLA lowering's precomputed tile (so both lowerings
+        # traverse on identical scores); each program reads its row
+        w = jnp.pad(
+            jnp.matmul(queries.astype(jnp.float32), xf.T, precision=_EXACT),
+            ((0, 0), (0, np_cols - n)))[:, None, :]
         # zero pad (NOT inf): scores are gathered by one-hot *matmul*, and
         # 0·inf = NaN would poison every gathered lane.  Padded slots are
         # only reachable through masked sentinel gathers, so a finite pad
@@ -646,15 +735,16 @@ def _fused_beam_pallas(
         dx_pad = _round_up(dx, LANE)
         xex = jnp.pad(x_exact, ((0, np_cols - n), (0, dx_pad - dx)))
         qex = jnp.pad(q_exact, ((0, 0), (0, dx_pad - dx)))
-    else:  # dummies keep one kernel signature
+    else:
         dx_pad = LANE
-        xex = jnp.zeros((np_cols, dx_pad), jnp.float32)
+        xex = jnp.zeros((8, dx_pad), jnp.float32)
         qex = jnp.zeros((nq, dx_pad), jnp.float32)
     k_out = rerank_k if rerank_k is not None else k
 
     kernel = functools.partial(
         _beam_kernel,
-        n=n, np_cols=np_cols, d_real=d, n_entries=entries.shape[0],
+        n=n, np_cols=np_cols, chunk=chunk, d_real=d,
+        n_entries=entries.shape[0],
         k=k, width=width, n_iters=n_iters, expand=expand, metric=metric,
         stage=stage, rerank_k=rerank_k,
     )
@@ -662,8 +752,9 @@ def _fused_beam_pallas(
         num_scalar_prefetch=1,
         grid=(nq,),
         in_specs=[
-            pl.BlockSpec((1, d_pad), lambda i, ent: (i, 0)),
-            pl.BlockSpec((np_cols, d_pad), lambda i, ent: (0, 0)),
+            pl.BlockSpec((None, 1, d_pad), lambda i, ent: (i, 0, 0)),
+            pl.BlockSpec((None, 1, w.shape[2]), lambda i, ent: (i, 0, 0)),
+            pl.BlockSpec(xp.shape, lambda i, ent: (0, 0)),
             pl.BlockSpec((np_cols, graph.shape[1]), lambda i, ent: (0, 0)),
             pl.BlockSpec((1, np_cols), lambda i, ent: (0, 0)),
             pl.BlockSpec((1, np_cols), lambda i, ent: (0, 0)),
@@ -671,38 +762,42 @@ def _fused_beam_pallas(
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1), lambda i, ent: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((np_cols, dx_pad), lambda i, ent: (0, 0)),
-            pl.BlockSpec((1, dx_pad), lambda i, ent: (i, 0)),
+            pl.BlockSpec(xex.shape, lambda i, ent: (0, 0)),
+            pl.BlockSpec((None, 1, dx_pad), lambda i, ent: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k_out), lambda i, ent: (i, 0)),
-            pl.BlockSpec((1, k_out), lambda i, ent: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, ent: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, ent: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, ent: (i, 0)),
+            pl.BlockSpec((None, 1, k_out), lambda i, ent: (i, 0, 0)),
+            pl.BlockSpec((None, 1, k_out), lambda i, ent: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i, ent: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i, ent: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i, ent: (i, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, np_cols), jnp.int32),  # visited tags
             pltpu.VMEM((1, width), jnp.float32),  # candidate distances
             pltpu.VMEM((1, width), jnp.int32),  # candidate ids
             pltpu.VMEM((1, width), jnp.int32),  # expanded flags
+            pltpu.VMEM((2, np_cols), jnp.float32),  # score row(s)
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nq, k_out), jnp.int32),
-            jax.ShapeDtypeStruct((nq, k_out), jnp.float32),
-            jax.ShapeDtypeStruct((nq, 1), jnp.int32),
-            jax.ShapeDtypeStruct((nq, 1), jnp.int32),
-            jax.ShapeDtypeStruct((nq, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, k_out), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, k_out), jnp.float32),
+            jax.ShapeDtypeStruct((nq, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, 1), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(entries, qp, xp, gp, aux1, aux2,
-      jnp.reshape(scale, (1, 1)), jnp.reshape(zp, (1, 1)), xex, qex)
+    )(entries, qp[:, None, :], w, xp, gp, aux1, aux2,
+      jnp.reshape(scale, (1, 1)), jnp.reshape(zp, (1, 1)), xex,
+      qex[:, None, :])
     ids, ds, nd, hp, nrr = out
-    return ids, ds, nd[:, 0], hp[:, 0], nrr[:, 0]
+    return ids[:, 0], ds[:, 0], nd[:, 0, 0], hp[:, 0, 0], nrr[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -779,6 +874,15 @@ def fused_beam(
         )
     if lowering not in ("pallas", "pallas_interpret"):
         raise ValueError(f"unknown fused_beam lowering {lowering!r}")
+    need = pallas_vmem_bytes(
+        x.shape[0], x.shape[1], graph.shape[1], x.dtype,
+        dx=None if x_exact is None else x_exact.shape[1])
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"fused_beam Pallas lowering: a [{x.shape[0]}, {x.shape[1]}] "
+            f"{x.dtype} shard of degree {graph.shape[1]} needs {need} bytes "
+            f"of resident VMEM, above the {VMEM_LIMIT_BYTES}-byte bound it "
+            "compiles under")
     return _fused_beam_pallas(
         x, graph, entries, queries, scale, zp, x_exact, q_exact,
         k=k, width=width, n_iters=n_iters, expand=expand, metric=metric,

@@ -36,7 +36,8 @@ def _distance_kernel(q_ref, x_ref, out_ref, *, metric: str):
     x = x_ref[...].astype(jnp.float32)  # [bn, D]
     # MXU: [bm, D] @ [D, bn]
     dots = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     if metric == "l2":
         qn = jnp.sum(q * q, axis=1, keepdims=True)  # [bm, 1]

@@ -11,6 +11,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+# f32 products at full precision: a TPU's default f32 matmul rounds its
+# operands to bf16, and the oracles are exact references
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 # ---------------------------------------------------------------------------
 # Pairwise distances
@@ -23,13 +27,14 @@ def pairwise_l2(q: jax.Array, x: jax.Array) -> jax.Array:
     x = x.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=-1, keepdims=True)  # [M, 1]
     xn = jnp.sum(x * x, axis=-1)[None, :]  # [1, N]
-    d = qn + xn - 2.0 * (q @ x.T)
+    d = qn + xn - 2.0 * jnp.matmul(q, x.T, precision=_EXACT)
     return jnp.maximum(d, 0.0)
 
 
 def pairwise_ip(q: jax.Array, x: jax.Array) -> jax.Array:
     """Negative inner product (so that smaller == closer). -> [M, N]."""
-    return -(q.astype(jnp.float32) @ x.astype(jnp.float32).T)
+    return -jnp.matmul(q.astype(jnp.float32), x.astype(jnp.float32).T,
+                       precision=_EXACT)
 
 
 def pairwise_distance(q, x, metric: str = "l2") -> jax.Array:

@@ -8,8 +8,8 @@ distance tiles with warp-level bitonic networks.  The TPU-native adaptation:
   * the running (bm, k) candidate list lives in the output VMEM block and is
     merged with each (bm, bn) tile by a **vectorized bitonic sort network**
     operating on VREG lanes (`jnp.where` compare-exchange + XOR-block
-    permutations implemented as reshape/flip — no gather, no sort primitive,
-    so it lowers on Mosaic);
+    permutations implemented as lane rolls + select — no gather, no sort
+    primitive, so it lowers on Mosaic);
   * the grid's inner dimension walks the N panels, revisiting the same output
     block (standard Pallas accumulation pattern), so each query panel's
     candidate list never leaves VMEM until the scan over N completes.
@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_M = 128
 BLOCK_N = 128
@@ -35,13 +36,13 @@ def _next_pow2(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
-def _xor_permute(a: jax.Array, stride: int) -> jax.Array:
-    """a[..., i] -> a[..., i ^ stride] via reshape+flip (Mosaic-friendly)."""
-    shape = a.shape
-    length = shape[-1]
-    a = a.reshape(*shape[:-1], length // (2 * stride), 2, stride)
-    a = jnp.flip(a, axis=-2)
-    return a.reshape(shape)
+def _xor_permute(a: jax.Array, stride: int, low: jax.Array) -> jax.Array:
+    """a[..., i] -> a[..., i ^ stride]: two lane rolls and a select on the
+    lane's ``stride`` bit (``low`` = bit clear).  Mosaic lowers rolls and
+    selects; it has no lowering for the reversal a reshape+flip needs."""
+    up = jnp.roll(a, -stride, axis=-1)  # a[i + stride]
+    down = jnp.roll(a, stride, axis=-1)  # a[i - stride]
+    return jnp.where(low, up, down)
 
 
 def bitonic_sort_lex(
@@ -84,21 +85,24 @@ def bitonic_sort_lex(
         size = 1 << size_exp
         for stride_exp in range(size_exp - 1, -1, -1):
             stride = 1 << stride_exp
-            pv = _xor_permute(vals, stride)
-            pi = _xor_permute(idxs, stride)
             up = (iota & size) == 0  # ascending run?
             i_low = (iota & stride) == 0  # lower element of its pair?
-            take_min = jnp.where(i_low, up, ~up)
+            pv = _xor_permute(vals, stride, i_low)
+            pi = _xor_permute(idxs, stride, i_low)
+            # bool logic, not bool-valued selects: Mosaic cannot select
+            # between masks
+            take_min = (i_low & up) | (~i_low & ~up)
             if tie_by_index:
                 le = (vals < pv) | ((vals == pv) & (idxs <= pi))
                 ge = (vals > pv) | ((vals == pv) & (idxs >= pi))
-                keep = jnp.where(take_min, le, ge)
             else:
-                keep = jnp.where(take_min, vals <= pv, vals >= pv)
+                le, ge = vals <= pv, vals >= pv
+            keep = (take_min & le) | (~take_min & ge)
             vals = jnp.where(keep, vals, pv)
             idxs = jnp.where(keep, idxs, pi)
             payloads = [
-                jnp.where(keep, p, _xor_permute(p, stride)) for p in payloads
+                jnp.where(keep, p, _xor_permute(p, stride, i_low))
+                for p in payloads
             ]
     return vals, idxs, tuple(payloads)
 
@@ -125,9 +129,10 @@ def merge_topk(vals, idxs, new_vals, new_idxs, k: int):
     return sv[..., :k], si[..., :k]
 
 
-def _knn_kernel(q_ref, x_ref, out_d_ref, out_i_ref, *, k, block_n, n_real,
+def _knn_kernel(n_ref, q_ref, x_ref, out_d_ref, out_i_ref, *, k, block_n,
                 metric):
     j = pl.program_id(1)
+    n_real = n_ref[0, 0]
 
     @pl.when(j == 0)
     def _init():
@@ -137,7 +142,8 @@ def _knn_kernel(q_ref, x_ref, out_d_ref, out_i_ref, *, k, block_n, n_real,
     q = q_ref[...].astype(jnp.float32)  # [bm, D]
     x = x_ref[...].astype(jnp.float32)  # [bn, D]
     dots = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     if metric == "l2":
         qn = jnp.sum(q * q, axis=1, keepdims=True)
@@ -154,7 +160,7 @@ def _knn_kernel(q_ref, x_ref, out_d_ref, out_i_ref, *, k, block_n, n_real,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "metric", "block_m", "block_n", "n_real", "interpret"),
+    static_argnames=("k", "metric", "block_m", "block_n", "interpret"),
 )
 def knn_pallas(
     q: jax.Array,
@@ -170,18 +176,19 @@ def knn_pallas(
     """Exact kNN: [M, D] queries × [N, D] points → ([M, k] dist, [M, k] idx).
 
     M, N, D must be block/lane aligned (``ops.knn`` pads); rows ≥ ``n_real``
-    in x are treated as padding.
+    in x are treated as padding.  ``n_real`` is traced (an SMEM scalar), so
+    shards of different sizes padded to one shape share one compile.
     """
     m, d = q.shape
     n, _ = x.shape
-    n_real = n if n_real is None else n_real
+    n_real = jnp.full((1, 1), n if n_real is None else n_real, jnp.int32)
     grid = (m // block_m, n // block_n)
     return pl.pallas_call(
-        functools.partial(
-            _knn_kernel, k=k, block_n=block_n, n_real=n_real, metric=metric
-        ),
+        functools.partial(_knn_kernel, k=k, block_n=block_n, metric=metric),
         grid=grid,
         in_specs=[
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((block_m, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
         ],
@@ -194,4 +201,4 @@ def knn_pallas(
             jax.ShapeDtypeStruct((m, k), jnp.int32),
         ],
         interpret=interpret,
-    )(q, x)
+    )(n_real, q, x)

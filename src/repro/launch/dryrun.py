@@ -143,10 +143,6 @@ def run_cell(arch_id: str, shape_id: str, *, multi_pod: bool,
     compile_s = time.perf_counter() - t0
 
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):
-        # older jax returns one dict per device program; they are
-        # replicas of the same program, so the first entry is the cost
-        xla_cost = xla_cost[0] if xla_cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_info = {

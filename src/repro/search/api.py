@@ -174,6 +174,12 @@ def search(
     centroid is probed.  Ignored on merged topologies (a merged graph has
     no shards to prune).
 
+    ``n_entries`` — seeds per beam besides the center entry: the merged
+    graph's medoid, or each probed shard's centroid-nearest row, plus
+    ``n_entries`` rows spread evenly over the index or shard
+    (:func:`repro.core.merge.entry_points`).  ``1`` seeds from the center
+    entry alone.
+
     ``dtype`` — the staged-precision distance path (PilotANN-style: cheap
     traversal, exact finish).  ``"f32"`` (default) is bit-identical to the
     historical path.  ``"bf16"`` streams vectors as bfloat16 (half the

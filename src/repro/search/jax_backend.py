@@ -117,7 +117,8 @@ def _batch_beam(
             def score(ids):
                 """‖x‖² − 2·q·x (L2 ranking without the per-query
                 constant) or −q·x for inner product."""
-                dots = x[ids].astype(jnp.float32) @ qf
+                dots = jnp.matmul(x[ids].astype(jnp.float32), qf,
+                                  precision=jax.lax.Precision.HIGHEST)
                 if metric == "ip":
                     return -dots
                 return xn[ids] - 2.0 * dots
@@ -307,12 +308,12 @@ def search_split(
     k: int,
     *,
     width: int = 64,
-    n_entries: int = 16,  # unused: shards seed from their centroid entry
+    n_entries: int = 16,
     n_iters: int | None = None,
     nprobe: NprobeSpec = None,
     dtype: str = "f32",
     rerank: int = DEFAULT_RERANK,
 ) -> tuple[np.ndarray, SearchStats]:
     return run_split(batch_beam_search, topo, queries, k, width=width,
-                     n_iters=n_iters, nprobe=nprobe, bucket=True,
-                     dtype=dtype, rerank=rerank)
+                     n_entries=n_entries, n_iters=n_iters, nprobe=nprobe,
+                     bucket=True, dtype=dtype, rerank=rerank)

@@ -212,7 +212,7 @@ def search_split(
     k: int,
     *,
     width: int = 64,
-    n_entries: int = 16,  # unused: shards seed from their centroid entry
+    n_entries: int = 16,
     nprobe: NprobeSpec = None,
     dtype: str = "f32",
     rerank: int = DEFAULT_RERANK,
@@ -230,4 +230,5 @@ def search_split(
     exactly and is counted separately as re-rank work.)
     """
     return run_split(_serial_batch_beam, topo, queries, k, width=width,
-                     nprobe=nprobe, dtype=dtype, rerank=rerank)
+                     n_entries=n_entries, nprobe=nprobe, dtype=dtype,
+                     rerank=rerank)

@@ -237,12 +237,12 @@ def search_split(
     k: int,
     *,
     width: int = 64,
-    n_entries: int = 16,  # unused: shards seed from their centroid entry
+    n_entries: int = 16,
     n_iters: int | None = None,
     nprobe: NprobeSpec = None,
     dtype: str = "f32",
     rerank: int = DEFAULT_RERANK,
 ) -> tuple[np.ndarray, SearchStats]:
     return run_split(fused_beam_search, topo, queries, k, width=width,
-                     n_iters=n_iters, nprobe=nprobe, bucket=True,
-                     dtype=dtype, rerank=rerank)
+                     n_entries=n_entries, n_iters=n_iters, nprobe=nprobe,
+                     bucket=True, dtype=dtype, rerank=rerank)

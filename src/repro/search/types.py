@@ -684,7 +684,7 @@ def _bucket_size(m: int) -> int:
 
 
 def run_split(beam_fn, topo: ShardTopology, queries, k: int, *,
-              width: int, n_iters: int | None = None,
+              width: int, n_entries: int, n_iters: int | None = None,
               nprobe: NprobeSpec = None, bucket: bool = False,
               dtype: str = "f32", rerank: int = DEFAULT_RERANK):
     """Shared split-topology driver: centroid-routed scatter + global re-rank.
@@ -700,11 +700,16 @@ def run_split(beam_fn, topo: ShardTopology, queries, k: int, *,
     :func:`parse_nprobe`) picks the probe count *per query* from the same
     tile: every shard whose centroid distance is within ``margin`` of the
     query's nearest centroid is probed, so easy queries (deep inside one
-    cluster) pay for one shard while boundary queries fan out.  Either way each shard search seeds from the local vector
-    nearest its centroid (:meth:`ShardTopology.shard_entries`; local row 0
-    without centroids), and per-shard beam scores are exact so the re-rank
-    reuses them — no extra distance computations.  The routing tile itself
-    is genuine per-query distance work and is counted.
+    cluster) pay for one shard while boundary queries fan out.  Either way
+    each shard search seeds from the local vector nearest its centroid
+    (:meth:`ShardTopology.shard_entries`; local row 0 without centroids)
+    plus, for ``n_entries > 1``, ``n_entries`` local rows spread evenly
+    over the shard (:func:`repro.core.merge.entry_points`, as the merged
+    driver seeds): a shard can hold parts of several clusters with no edges
+    between them, and a query reaches its part only from a seed in it.
+    Per-shard beam scores are exact so the re-rank reuses them — no extra
+    distance computations.  The routing tile itself is genuine per-query
+    distance work and is counted.
 
     ``bucket=True`` (the jitted backends) pads each shard's routed query
     group up to a bounded set of sizes (8 steps per power-of-two octave,
@@ -791,6 +796,8 @@ def run_split(beam_fn, topo: ShardTopology, queries, k: int, *,
             np.arange(n_live), (nq, n_live)
         )
     n_probe = probes.shape[1]
+    from repro.core.merge import entry_points  # deferred: import cycle
+
     entries = topo.shard_entries()
     staged = dtype != "f32"
     tomb = topo.tombstones
@@ -816,6 +823,8 @@ def run_split(beam_fn, topo: ShardTopology, queries, k: int, *,
             if b > m:
                 use_rows = np.resize(qrows, b)  # cycle real rows as padding
         ids = topo.shard_ids[s]
+        seeds = (entry_points(int(entries[s]), len(ids), n_entries)
+                 if n_entries > 1 else int(entries[s]))
         if staged:
             store, spec = shard_store[s]
             quant_kw = {"quant": spec if spec is not None else dtype}
@@ -823,7 +832,7 @@ def run_split(beam_fn, topo: ShardTopology, queries, k: int, *,
             store, quant_kw = f32_store[s], {}
         local, ld, s_stats = beam_fn(
             store, topo.shard_graphs[s],
-            int(entries[s]), queries[use_rows], min(kq, len(ids)),
+            seeds, queries[use_rows], min(kq, len(ids)),
             width=width, n_iters=n_iters, metric=topo.metric,
             n_real=m if use_rows is not qrows else None, **quant_kw,
         )

@@ -197,7 +197,7 @@ class AnnServer:
         self.stats.registry.counter(*_POST_WARM_METRIC)  # expose at zero
         if self.tracer.enabled:
             # compile events land on the same timeline as the requests
-            # they delay (idempotent; no-op without jax.monitoring)
+            # they delay (idempotent)
             install_compile_listener()
         if policy is None:
             policy = (AdaptiveWindow(cfg.max_wait_ms, cfg.max_batch)

@@ -19,8 +19,8 @@ turns that class from a rediscovery into two first-class metrics:
   where a retrace can land mid-traffic.  The serving layer feeds
   ``serving_post_warm_signatures_total`` from it.
 
-Both degrade to no-ops when JAX (or its monitoring API) is unavailable —
-telemetry must never be the reason a numpy-only path can't run.
+The listener imports ``jax.monitoring`` only when it is installed, so a
+numpy-only path that never starts a server or bench never loads JAX here.
 """
 
 from __future__ import annotations
@@ -62,25 +62,17 @@ def _on_duration(event: str, duration_s: float, **_kw) -> None:
                     event=short)
 
 
-def install_compile_listener() -> bool:
+def install_compile_listener() -> None:
     """Register the ``jax.monitoring`` duration listener (idempotent —
     safe to call from every server/bench startup).  Events are forwarded
     to whatever registry/tracer is *current at event time*, so a bench
-    that installs its own tracer after this still captures compiles.
-
-    Returns True when the listener is (already) installed, False when the
-    monitoring API is unavailable."""
+    that installs its own tracer after this still captures compiles."""
     global _installed
     with _install_lock:
-        if _installed:
-            return True
-        try:
+        if not _installed:
             from jax import monitoring
             monitoring.register_event_duration_secs_listener(_on_duration)
-        except Exception:  # noqa: BLE001 — no jax / changed API: degrade
-            return False
-        _installed = True
-        return True
+            _installed = True
 
 
 class SignatureGuard:

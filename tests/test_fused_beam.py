@@ -31,6 +31,7 @@ import pytest
 
 from repro.configs.base import IndexConfig
 from repro.core import builder
+from repro.core.merge import GlobalIndex
 from repro.data.synthetic import make_clustered
 from repro.kernels import beam as kb
 from repro.kernels import ops
@@ -224,3 +225,23 @@ def test_bitonic_lex_payloads_ride_the_same_permutation():
     sv, si, (sp,) = bitonic_sort_lex(vals, idxs, payloads=(pay,))
     np.testing.assert_array_equal(np.asarray(si)[0], [13, 11, 12, 10])
     np.testing.assert_array_equal(np.asarray(sp)[0], [133, 111, 122, 100])
+
+
+def test_pallas_backend_refuses_shard_above_vmem_bound():
+    """Above the compiled VMEM bound the Pallas lowering raises a named
+    error (bytes and bound) instead of switching to the XLA lowering."""
+    rng = np.random.default_rng(1)
+    n = 90_000  # uint8 + re-rank: the model is past VMEM_LIMIT_BYTES
+    assert kb.pallas_vmem_bytes(n, 128, 64, np.uint8, dx=128) \
+        > kb.VMEM_LIMIT_BYTES
+    data = rng.standard_normal((n, 128)).astype(np.float32)
+    graph = rng.integers(0, n, (n, 64)).astype(np.int32)
+    index = GlobalIndex(graph=graph, medoid=0, n_vectors=n)
+    topo = MergedTopology(data=data, index=index)
+    ops.set_pallas_mode("force_interpret")
+    try:
+        with pytest.raises(ValueError, match=r"needs \d+ bytes .* above "
+                           rf"the {kb.VMEM_LIMIT_BYTES}-byte bound"):
+            search(topo, data[:2], K, backend="pallas", dtype="uint8")
+    finally:
+        ops.set_pallas_mode("auto")

@@ -319,6 +319,31 @@ def test_shard_entries_are_centroid_nearest(routed_topo):
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
+def test_routed_shard_seeds_reach_every_component(backend):
+    """A shard can hold parts of two clusters with no edges between them
+    (capacity spills part of a cluster into a neighbour's shard).  Seeded
+    from the centroid entry alone, a query in the other part never reaches
+    it; ``n_entries`` stratified seeds put a seed in each part."""
+    rng = np.random.default_rng(3)
+    a = rng.normal(size=(40, 8)).astype(np.float32)
+    b = rng.normal(size=(24, 8)).astype(np.float32) + 50.0
+    data = np.concatenate([a, b])
+    ring = lambda n, off: np.stack(  # noqa: E731
+        [(np.arange(n) + s) % n + off for s in (1, 2, 3)], axis=1)
+    graph = np.concatenate([ring(40, 0), ring(24, 40)]).astype(np.int32)
+    topo = ShardTopology(data=data, shard_ids=[np.arange(64, dtype=np.int64)],
+                         shard_graphs=[graph],
+                         centroids=a.mean(axis=0, keepdims=True))
+    q = b[:4] + 0.01
+    want = np.argsort(((data[None] - q[:, None]) ** 2).sum(-1), axis=1)[:, :5]
+    kw = dict(backend=backend, width=32, nprobe=1)
+    one, _ = search(topo, q, 5, n_entries=1, **kw)
+    many, _ = search(topo, q, 5, n_entries=16, **kw)
+    assert (one < 40).all()  # stuck in the centroid entry's component
+    np.testing.assert_array_equal(np.sort(many, axis=1), np.sort(want, axis=1))
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
 def test_tiny_shard_pools_are_padded(backend):
     """Regression: a shard with fewer than k vectors returns a narrower
     per-shard pool; the split driver must pad it to k columns instead of
