@@ -48,7 +48,7 @@ from repro.configs.base import IndexConfig
 from repro.core import cagra, vamana
 from repro.core.merge import GlobalIndex, merge_shard_indexes
 from repro.core.partition import PartitionResult, Shard, partition
-from repro.telemetry import current_tracer
+from repro.telemetry import NULL_SPAN, current_tracer
 
 BUILDERS = {
     "cagra": cagra.build_shard_index,
@@ -205,7 +205,8 @@ def _build_shards(
         other shards' work (paper §IV: failed tasks are re-allocated, not
         fatal).  The final failure is recorded, not raised, so every shard
         gets its full retry budget before the build surfaces one error."""
-        vecs = np.asarray(data[shards[i].ids])
+        with tr.span("build.gather") if tr.enabled else NULL_SPAN:
+            vecs = np.asarray(data[shards[i].ids])
         t0 = time.perf_counter()
         for attempt in range(max_retries + 1):
             attempts[i] = attempt + 1

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.configs.base import IndexConfig
 from repro.kernels import ops
+from repro.telemetry import NULL_SPAN, current_tracer
 
 
 @dataclasses.dataclass
@@ -218,27 +219,43 @@ def optimize_graph(
     reverse-edge fill and the row dedup/refill instead of the vectorized
     segment-scatter / sort-dedup paths — same output bit for bit
     (parity-tested), kept as the ``bench_build.py`` seed-loop baseline.
+
+    Under an enabled tracer, ``cagra.detour`` spans the detour counts
+    (host gathers, uploads, device blocks, reads back) and ``cagra.prune``
+    the host pruning that follows.
     """
     n, L = nbrs.shape
     x = vectors.astype(np.float32)
-    fwd_keep = R - R // 2
     n_dist = 0
     counts = np.empty((n, L), np.int64)
     safe_nbrs = np.maximum(nbrs, 0)
     nb = min(node_block, n)
-    for s in range(0, n, nb):
-        e = min(s + nb, n)
-        # fixed block shape (the last block repeats row n-1, dropped
-        # below): one compile per shard size, not one per remainder
-        rows = np.minimum(np.arange(s, s + nb), n - 1)
-        c = _detour_counts(
-            jnp.asarray(x[rows]),
-            jnp.asarray(x[safe_nbrs[rows]]),
-            jnp.asarray(dists[rows]),
-            metric,
-        )
-        counts[s:e] = np.asarray(c)[: e - s]
-        n_dist += (e - s) * L * L
+    tr = current_tracer()
+    with tr.span("cagra.detour") if tr.enabled else NULL_SPAN:
+        for s in range(0, n, nb):
+            e = min(s + nb, n)
+            # fixed block shape (the last block repeats row n-1, dropped
+            # below): one compile per shard size, not one per remainder
+            rows = np.minimum(np.arange(s, s + nb), n - 1)
+            c = _detour_counts(
+                jnp.asarray(x[rows]),
+                jnp.asarray(x[safe_nbrs[rows]]),
+                jnp.asarray(dists[rows]),
+                metric,
+            )
+            counts[s:e] = np.asarray(c)[: e - s]
+            n_dist += (e - s) * L * L
+    with tr.span("cagra.prune") if tr.enabled else NULL_SPAN:
+        graph = _prune(nbrs, counts, R, reference)
+    return graph, n_dist
+
+
+def _prune(nbrs: np.ndarray, counts: np.ndarray, R: int,
+           reference: bool) -> np.ndarray:
+    """Keep each row's R/2 forward edges with the fewest detours, add
+    reverse edges, dedup and refill from the leftover kNN (int32)."""
+    n = len(nbrs)
+    fwd_keep = R - R // 2
     # stable: prefer fewer detours, break ties by distance rank (ascending)
     order = np.argsort(counts, axis=1, kind="stable")
     fwd = np.take_along_axis(nbrs, order[:, :fwd_keep], axis=1)  # [n, R/2]
@@ -260,7 +277,7 @@ def optimize_graph(
     leftover = np.take_along_axis(nbrs, order[:, fwd_keep:], axis=1)
     dedup = _dedup_refill_loop if reference else _dedup_refill_rows
     graph = dedup(graph, leftover, R)
-    return graph.astype(np.int32), n_dist
+    return graph.astype(np.int32)
 
 
 def build_shard_index(
@@ -269,10 +286,13 @@ def build_shard_index(
     """Full CAGRA-style build of one shard (the spot-instance task body).
 
     ``reference=True`` routes :func:`optimize_graph` through its original
-    per-node python loops (bit-identical output; the seed-loop baseline)."""
-    nbrs, dists, nd1 = build_knn_graph(
-        vectors, cfg.build_degree, metric=cfg.metric
-    )
+    per-node python loops (bit-identical output; the seed-loop baseline).
+    Under an enabled tracer the kNN stage is the span ``cagra.knn``."""
+    tr = current_tracer()
+    with tr.span("cagra.knn") if tr.enabled else NULL_SPAN:
+        nbrs, dists, nd1 = build_knn_graph(
+            vectors, cfg.build_degree, metric=cfg.metric
+        )
     graph, nd2 = optimize_graph(
         vectors, nbrs, dists, cfg.degree, metric=cfg.metric,
         reference=reference,

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core.cagra import ShardIndex
 from repro.core.partition import Shard
+from repro.telemetry import NULL_SPAN, current_tracer
 
 
 def entry_points(center: int, n_vectors: int, n: int = 16) -> np.ndarray:
@@ -172,36 +173,45 @@ def _union_dedup_cap(
     so the permutation-invariance contract (§V-C) is preserved — only the
     within-row order of an under-capacity ``data`` row differs from the
     loop (distance-sorted instead of first-seen; same id set).
+
+    Under an enabled tracer its stages are the spans ``merge.edges``,
+    ``merge.sort``, ``merge.distances`` and ``merge.cap``.
     """
+    tr = current_tracer()
     graph = np.full((n_total, degree), -1, np.int32)
-    gids, nbrs = _edge_list(shards, indexes)
+    with tr.span("merge.edges") if tr.enabled else NULL_SPAN:
+        gids, nbrs = _edge_list(shards, indexes)
     if gids.size == 0:
         return graph
-    # dedup: stable (gid, nbr) sort keeps the earliest appended copy first
-    # (one int64 key sorts like lexsort((nbrs, gids)), in half the time)
-    order = np.argsort(gids * n_total + nbrs, kind="stable")
-    sg, sn = gids[order], nbrs[order]
-    first = np.ones(len(sg), bool)
-    first[1:] = (sg[1:] != sg[:-1]) | (sn[1:] != sn[:-1])
-    ug, un, upos = sg[first], sn[first], order[first]
+    with tr.span("merge.sort") if tr.enabled else NULL_SPAN:
+        # dedup: stable (gid, nbr) sort keeps the earliest appended copy
+        # first (one int64 key sorts like lexsort((nbrs, gids)), in half
+        # the time)
+        order = np.argsort(gids * n_total + nbrs, kind="stable")
+        sg, sn = gids[order], nbrs[order]
+        first = np.ones(len(sg), bool)
+        first[1:] = (sg[1:] != sg[:-1]) | (sn[1:] != sn[:-1])
+        ug, un, upos = sg[first], sn[first], order[first]
     # cap: order each gid's unique neighbors by (distance, first-seen) or
     # (first-seen) alone, then keep ranks < degree.  A stable sort of the
     # first-seen order by an int64 (gid, distance) key sorts like
     # lexsort((upos, d, ug)): non-negative f32 bit patterns order as values.
-    by_pos = np.argsort(upos)
     if data is not None:
-        d = _segment_distances(data, ug, un)
-        key = (ug << 32) | d.view(np.int32).astype(np.int64)
+        with tr.span("merge.distances") if tr.enabled else NULL_SPAN:
+            d = _segment_distances(data, ug, un)
+            key = (ug << 32) | d.view(np.int32).astype(np.int64)
     else:
         key = ug
-    sel = by_pos[np.argsort(key[by_pos], kind="stable")]
-    g2, n2 = ug[sel], un[sel]
-    idx = np.arange(len(sel))
-    seg_start = np.ones(len(sel), bool)
-    seg_start[1:] = g2[1:] != g2[:-1]
-    rank = idx - np.maximum.accumulate(np.where(seg_start, idx, 0))
-    keep = rank < degree
-    graph[g2[keep], rank[keep]] = n2[keep].astype(np.int32)
+    with tr.span("merge.cap") if tr.enabled else NULL_SPAN:
+        by_pos = np.argsort(upos)
+        sel = by_pos[np.argsort(key[by_pos], kind="stable")]
+        g2, n2 = ug[sel], un[sel]
+        idx = np.arange(len(sel))
+        seg_start = np.ones(len(sel), bool)
+        seg_start[1:] = g2[1:] != g2[:-1]
+        rank = idx - np.maximum.accumulate(np.where(seg_start, idx, 0))
+        keep = rank < degree
+        graph[g2[keep], rank[keep]] = n2[keep].astype(np.int32)
     return graph
 
 

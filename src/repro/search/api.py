@@ -220,14 +220,14 @@ def search(
         topo = dataclasses.replace(topo, metric=metric)
     impl = get_backend(backend)
     queries = np.asarray(queries, np.float32)
-    from repro.telemetry import current_tracer
+    from repro.telemetry import NULL_SPAN, current_tracer
 
     tr = current_tracer()
     if tr.enabled:  # the gate keeps the untraced path allocation-free
         span = tr.span("search.engine", backend=backend,
                        n_queries=len(queries), k=k, dtype=dtype)
     else:
-        span = tr.span()  # the shared no-op span
+        span = NULL_SPAN
     with span:
         if isinstance(topo, MergedTopology):
             ids, stats = impl.search_merged(

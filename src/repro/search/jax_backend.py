@@ -49,6 +49,7 @@ import numpy as np
 from repro.search.types import (DEFAULT_RERANK, MergedTopology, NprobeSpec,
                                 QuantSpec, SearchStats, ShardTopology,
                                 run_merged, run_split)
+from repro.telemetry import NULL_SPAN, current_tracer
 
 
 def default_n_iters(width: int) -> int:
@@ -222,7 +223,7 @@ def _prep_entries(entries, width: int) -> np.ndarray:
 
 
 def _prep_stage(data, queries, quant):
-    """(x, q, scale, zp) device inputs for one distance stage.
+    """(x, q, scale, zp) host inputs for one distance stage.
 
     ``quant=None`` — exact f32 (any raw input dtype is cast, the historical
     path); ``"bf16"`` — data is a bf16 copy, queries round to bf16;
@@ -230,17 +231,13 @@ def _prep_stage(data, queries, quant):
     the same spec into int32 code vectors.
     """
     if isinstance(quant, QuantSpec):
-        x = jnp.asarray(np.asarray(data))
-        q = jnp.asarray(quant.quantize(queries).astype(np.int32))
-        return x, q, jnp.float32(quant.scale), jnp.float32(quant.zero_point)
+        return (np.asarray(data), quant.quantize(queries).astype(np.int32),
+                jnp.float32(quant.scale), jnp.float32(quant.zero_point))
+    q = np.asarray(queries, np.float32)
     if quant == "bf16":
-        x = jnp.asarray(data)
-        q = jnp.asarray(np.asarray(queries, np.float32)).astype(
-            jnp.bfloat16)
-        return x, q, jnp.float32(0), jnp.float32(0)
-    x = jnp.asarray(np.asarray(data, np.float32))
-    q = jnp.asarray(np.asarray(queries, np.float32))
-    return x, q, jnp.float32(0), jnp.float32(0)
+        return (np.asarray(data), q.astype(jnp.bfloat16), jnp.float32(0),
+                jnp.float32(0))
+    return np.asarray(data, np.float32), q, jnp.float32(0), jnp.float32(0)
 
 
 def batch_beam_search(
@@ -262,24 +259,31 @@ def batch_beam_search(
     ``n_real`` — count stats over the first ``n_real`` queries only (the
     routed split driver pads query groups to stable jit shapes by cycling
     real rows; padded lanes must not inflate the stats).
+
+    Under an enabled tracer, ``beam.stage`` spans the host staging and
+    upload of the inputs and ``beam.device`` the jitted call and the reads
+    of its outputs.
     """
     n_iters = default_n_iters(width) if n_iters is None else n_iters
-    e = _prep_entries(entries, width)
-    x, q, scale, zp = _prep_stage(data, queries, quant)
-    ids, ds, n_dist, hops = _batch_beam(
-        x,
-        jnp.asarray(np.asarray(graph), jnp.int32),
-        jnp.asarray(e),
-        q,
-        k, width, n_iters, expand, metric, scale, zp,
-    )
-    nd = int(np.asarray(n_dist)[:n_real].sum())
-    stats = SearchStats(
-        n_distance_computations=nd,
-        n_hops=int(np.asarray(hops)[:n_real].sum()),
-        n_quantized_distance_computations=nd if quant is not None else 0,
-    )
-    return np.asarray(ids, np.int64), np.asarray(ds), stats
+    tr = current_tracer()
+    with tr.span("beam.stage") if tr.enabled else NULL_SPAN:
+        e = _prep_entries(entries, width)
+        x, q, scale, zp = _prep_stage(data, queries, quant)
+        g = np.asarray(graph, np.int32)
+        h2d_bytes = x.nbytes + g.nbytes + e.nbytes + q.nbytes
+        x, g, e, q = (jnp.asarray(a) for a in (x, g, e, q))
+    with tr.span("beam.device") if tr.enabled else NULL_SPAN:
+        ids, ds, n_dist, hops = _batch_beam(
+            x, g, e, q, k, width, n_iters, expand, metric, scale, zp,
+        )
+        nd = int(np.asarray(n_dist)[:n_real].sum())
+        stats = SearchStats(
+            n_distance_computations=nd,
+            n_hops=int(np.asarray(hops)[:n_real].sum()),
+            n_quantized_distance_computations=nd if quant is not None else 0,
+            h2d_bytes=h2d_bytes,
+        )
+        return np.asarray(ids, np.int64), np.asarray(ds), stats
 
 
 # raw batched-beam hook for build-time searches (`repro.search.beam_pool`)

@@ -21,28 +21,14 @@ of the old ``core.search`` module.
 from __future__ import annotations
 
 import dataclasses
-import time
 import typing
 
 import numpy as np
 
-from repro.telemetry import record_stage, stage_active
+from repro.telemetry import NULL_SPAN, current_tracer
 
 if typing.TYPE_CHECKING:  # import-time independence from repro.core
     from repro.core.merge import GlobalIndex
-
-
-def _rerank_exact_timed(ops, data, cand, queries, k, metric):
-    """The shared exact-f32 epilogue, reporting its wall time to any
-    enclosing :func:`repro.telemetry.collect_stages` block (the serving
-    worker splits engine vs re-rank time per request from it).  With no
-    collector active this is a plain call — not even a clock read."""
-    if not stage_active():
-        return ops.rerank_exact(data, cand, queries, k, metric)
-    t0 = time.perf_counter()
-    out = ops.rerank_exact(data, cand, queries, k, metric)
-    record_stage("search.rerank", time.perf_counter() - t0)
-    return out
 
 
 @dataclasses.dataclass
@@ -62,6 +48,12 @@ class SearchStats:
     the cheap dtype, ``n_rerank_distance_computations`` the exact f32
     epilogue scores — the two sides of the staged memory-traffic trade.
     Both stay 0 on the f32 path.
+
+    ``n_beam_calls`` counts the beam searches the drivers dispatch (one
+    per probed shard on the split path), and ``h2d_bytes`` the bytes of
+    the host arrays the ``jax`` beam uploads for them (store or codes,
+    graph, entries, queries).  Bucket padding scales neither: those calls
+    and bytes really happen.
     """
 
     n_distance_computations: int = 0
@@ -69,6 +61,8 @@ class SearchStats:
     n_queries: int = 0
     n_quantized_distance_computations: int = 0
     n_rerank_distance_computations: int = 0
+    n_beam_calls: int = 0
+    h2d_bytes: int = 0
 
     def __iadd__(self, other: "SearchStats"):
         self.n_distance_computations += other.n_distance_computations
@@ -78,6 +72,8 @@ class SearchStats:
             other.n_quantized_distance_computations)
         self.n_rerank_distance_computations += (
             other.n_rerank_distance_computations)
+        self.n_beam_calls += other.n_beam_calls
+        self.h2d_bytes += other.h2d_bytes
         return self
 
     def per_query(self) -> dict:
@@ -440,6 +436,7 @@ def run_merged(beam_fn, topo: MergedTopology, queries, k: int, *,
             topo.data, topo.index.graph, entries, queries, kq,
             width=width, n_iters=n_iters, metric=topo.metric,
         )
+        stats.n_beam_calls += 1
         if tomb is not None:
             ids = drop_tombstones(ids, tomb, k)
         return ids, stats
@@ -448,8 +445,10 @@ def run_merged(beam_fn, topo: MergedTopology, queries, k: int, *,
     if fused is not None and tomb is None:
         # the fused device dispatch has no tombstone mask — deletes fall
         # back to the host epilogue below, which masks before re-ranking
-        return fused(topo, entries, queries, k, kq, width=width,
-                     n_iters=n_iters, dtype=dtype)
+        ids, stats = fused(topo, entries, queries, k, kq, width=width,
+                           n_iters=n_iters, dtype=dtype)
+        stats.n_beam_calls += 1
+        return ids, stats
     from repro.kernels import ops  # deferred: keep the f32 path jax-free
 
     store, spec = topo.quant_view(dtype)
@@ -458,6 +457,7 @@ def run_merged(beam_fn, topo: MergedTopology, queries, k: int, *,
         width=width, n_iters=n_iters, metric=topo.metric,
         quant=spec if spec is not None else dtype,
     )
+    stats.n_beam_calls += 1
     if tomb is not None:
         # rerank_exact tolerates -1 candidates (scored at inf, emitted as
         # -1 pad), so masking here keeps dead ids out of the final top-k
@@ -465,9 +465,8 @@ def run_merged(beam_fn, topo: MergedTopology, queries, k: int, *,
             (np.asarray(cand, np.int64) >= 0)
             & tomb[np.maximum(cand, 0)], -1, cand,
         )
-    ids, _, n_scored = _rerank_exact_timed(
-        ops, topo.data, cand, np.asarray(queries, np.float32), k,
-        topo.metric,
+    ids, _, n_scored = ops.rerank_exact(
+        topo.data, cand, np.asarray(queries, np.float32), k, topo.metric,
     )
     stats.n_distance_computations += n_scored
     stats.n_rerank_distance_computations += n_scored
@@ -735,6 +734,11 @@ def run_split(beam_fn, topo: ShardTopology, queries, k: int, *,
     stay comparable across shards), counted as quantized work; ``"bf16"``
     keeps the f32 tile (the tile is compute-shaped, and bf16's win is
     storage streaming, not the tiny centroid set).
+
+    Under an enabled tracer each stage is a span: ``split.route`` (the
+    tile and the probe choice), ``split.beam`` (one per probed shard),
+    ``split.pool`` (tombstone mask and pool merge) and ``split.rerank``
+    (the exact epilogue).
     """
     queries = np.asarray(queries, np.float32)
     nq = len(queries)
@@ -743,58 +747,10 @@ def run_split(beam_fn, topo: ShardTopology, queries, k: int, *,
     live = [s for s, ids in enumerate(topo.shard_ids) if len(ids) > 0]
     if not live or nq == 0:
         return np.full((nq, k), -1, np.int64), stats
-    n_live = len(live)
-    route = mode != "scatter" and topo.centroids is not None
-    if route:
-        if dtype == "uint8":
-            # quantized routing tile + certified-exact fallback: queries
-            # whose decision the code-domain error bound cannot certify
-            # (or that clip outside the spec's range) rescore their row in
-            # f32, so routing decisions are identical to the f32 tile
-            codes, spec, resid = topo.centroid_quant()
-            qc, qerr, amb = _query_centroid_distances_u8(
-                queries, codes[live], spec, resid[live], topo.metric
-            )
-            stats.n_distance_computations += nq * n_live
-            stats.n_quantized_distance_computations += nq * n_live
-            pre = np.argsort(qc, axis=1, kind="stable")
-            amb = amb | _ambiguous_routing(
-                np.take_along_axis(qc, pre, axis=1),
-                np.take_along_axis(qerr, pre, axis=1),
-                mode, count, margin,
-            )
-            n_amb = int(amb.sum())
-            if n_amb:
-                cent = np.asarray(topo.centroids, np.float32)[live]
-                qc[amb] = _query_centroid_distances(
-                    queries[amb], cent, topo.metric
-                )
-                stats.n_distance_computations += n_amb * n_live
-        else:
-            cent = np.asarray(topo.centroids, np.float32)[live]
-            qc = _query_centroid_distances(queries, cent, topo.metric)
-            stats.n_distance_computations += nq * n_live
-        # [Q, n_live] positions into `live`, nearest shard first
-        order = np.argsort(qc, axis=1, kind="stable")
-        if mode == "fixed":
-            probes = order[:, :min(count, n_live)]
-        else:
-            # adaptive: probe every shard whose centroid distance is within
-            # `margin` of the query's nearest (d <= d1 + (margin-1)·|d1|,
-            # which is margin·d1 for the non-negative squared-L2 case and
-            # degrades gracefully for negated inner products); distances
-            # are sorted, so the kept set is a per-query prefix and -1
-            # marks each query's unused probe slots
-            sd = np.take_along_axis(qc, order, axis=1)
-            d1 = sd[:, :1]
-            keep = sd <= d1 + (margin - 1.0) * np.abs(d1)
-            keep[:, 0] = True  # the nearest shard is always probed
-            probes = np.where(keep, order, -1)
-            probes = probes[:, : int(keep.sum(axis=1).max())]
-    else:
-        probes = np.broadcast_to(
-            np.arange(n_live), (nq, n_live)
-        )
+    tr = current_tracer()
+    with tr.span("split.route") if tr.enabled else NULL_SPAN:
+        probes = _route(topo, queries, live, mode, count, margin, dtype,
+                        stats)
     n_probe = probes.shape[1]
     from repro.core.merge import entry_points  # deferred: import cycle
 
@@ -812,55 +768,115 @@ def run_split(beam_fn, topo: ShardTopology, queries, k: int, *,
         f32_store = topo.shard_store()  # cached: stable storage identity
     pool_ids = np.full((nq, n_probe, kq), -1, np.int64)
     pool_d = np.full((nq, n_probe, kq), np.inf, np.float32)
-    for p, s in enumerate(live):
-        qrows, slots = np.nonzero(probes == p)
-        m = qrows.size
-        if m == 0:
-            continue
-        use_rows = qrows
-        if bucket and m < nq:
-            b = min(_bucket_size(m), nq)
-            if b > m:
-                use_rows = np.resize(qrows, b)  # cycle real rows as padding
-        ids = topo.shard_ids[s]
-        seeds = (entry_points(int(entries[s]), len(ids), n_entries)
-                 if n_entries > 1 else int(entries[s]))
-        if staged:
-            store, spec = shard_store[s]
-            quant_kw = {"quant": spec if spec is not None else dtype}
-        else:
-            store, quant_kw = f32_store[s], {}
-        local, ld, s_stats = beam_fn(
-            store, topo.shard_graphs[s],
-            seeds, queries[use_rows], min(kq, len(ids)),
-            width=width, n_iters=n_iters, metric=topo.metric,
-            n_real=m if use_rows is not qrows else None, **quant_kw,
-        )
-        stats += s_stats
-        local, ld = pad_pool(local[:m], ld[:m], kq)
-        gids = np.where(local >= 0, ids[np.maximum(local, 0)], -1)
-        pool_ids[qrows, slots] = gids
-        pool_d[qrows, slots] = np.where(local >= 0, ld, np.inf)
-    flat_ids = pool_ids.reshape(nq, n_probe * kq)
-    flat_d = pool_d.reshape(nq, n_probe * kq)
-    if tomb is not None:
-        dead = (flat_ids >= 0) & tomb[np.maximum(flat_ids, 0)]
-        flat_ids = np.where(dead, -1, flat_ids)
-        flat_d = np.where(dead, np.inf, flat_d)
-    # f32: pool distances are exact, so the merge takes the final top-k
-    # directly; staged: keep kq candidates for the exact re-rank epilogue
-    merged = rerank_shard_pools(flat_ids, flat_d, kq if staged else k)
+    for p in np.unique(probes[probes >= 0]):  # the probed shards, in order
+        s = live[p]
+        with tr.span("split.beam") if tr.enabled else NULL_SPAN:
+            qrows, slots = np.nonzero(probes == p)
+            m = qrows.size
+            use_rows = qrows
+            if bucket and m < nq:
+                b = min(_bucket_size(m), nq)
+                if b > m:
+                    use_rows = np.resize(qrows, b)  # cycle real rows
+            ids = topo.shard_ids[s]
+            seeds = (entry_points(int(entries[s]), len(ids), n_entries)
+                     if n_entries > 1 else int(entries[s]))
+            if staged:
+                store, spec = shard_store[s]
+                quant_kw = {"quant": spec if spec is not None else dtype}
+            else:
+                store, quant_kw = f32_store[s], {}
+            local, ld, s_stats = beam_fn(
+                store, topo.shard_graphs[s],
+                seeds, queries[use_rows], min(kq, len(ids)),
+                width=width, n_iters=n_iters, metric=topo.metric,
+                n_real=m if use_rows is not qrows else None, **quant_kw,
+            )
+            stats += s_stats
+            stats.n_beam_calls += 1
+            local, ld = pad_pool(local[:m], ld[:m], kq)
+            gids = np.where(local >= 0, ids[np.maximum(local, 0)], -1)
+            pool_ids[qrows, slots] = gids
+            pool_d[qrows, slots] = np.where(local >= 0, ld, np.inf)
+    with tr.span("split.pool") if tr.enabled else NULL_SPAN:
+        flat_ids = pool_ids.reshape(nq, n_probe * kq)
+        flat_d = pool_d.reshape(nq, n_probe * kq)
+        if tomb is not None:
+            dead = (flat_ids >= 0) & tomb[np.maximum(flat_ids, 0)]
+            flat_ids = np.where(dead, -1, flat_ids)
+            flat_d = np.where(dead, np.inf, flat_d)
+        # f32: pool distances are exact, so the merge takes the final
+        # top-k directly; staged: keep kq candidates for the exact re-rank
+        merged = rerank_shard_pools(flat_ids, flat_d, kq if staged else k)
     if not staged:
         return merged, stats
     # one exact-f32 epilogue per query over the merged quantized top-kq
     from repro.kernels import ops  # deferred: keep the f32 path jax-free
 
-    out, _, n_scored = _rerank_exact_timed(
-        ops, topo.data, merged, queries, k, topo.metric
-    )
+    with tr.span("split.rerank") if tr.enabled else NULL_SPAN:
+        out, _, n_scored = ops.rerank_exact(
+            topo.data, merged, queries, k, topo.metric
+        )
     stats.n_distance_computations += n_scored
     stats.n_rerank_distance_computations += n_scored
     return out, stats
+
+
+def _route(topo: ShardTopology, queries: np.ndarray, live: list, mode: str,
+           count: int, margin: float, dtype: str,
+           stats: SearchStats) -> np.ndarray:
+    """``[Q, n_probe]`` positions into ``live`` of the shards each query
+    probes, nearest first (-1 marks unused slots under ``"auto"``); counts
+    the routing tile's distance work into ``stats``.  Scatter (or a
+    topology without centroids) probes every live shard."""
+    nq = len(queries)
+    n_live = len(live)
+    if mode == "scatter" or topo.centroids is None:
+        return np.broadcast_to(np.arange(n_live), (nq, n_live))
+    if dtype == "uint8":
+        # quantized routing tile + certified-exact fallback: queries whose
+        # decision the code-domain error bound cannot certify (or that clip
+        # outside the spec's range) rescore their row in f32, so routing
+        # decisions are identical to the f32 tile
+        codes, spec, resid = topo.centroid_quant()
+        qc, qerr, amb = _query_centroid_distances_u8(
+            queries, codes[live], spec, resid[live], topo.metric
+        )
+        stats.n_distance_computations += nq * n_live
+        stats.n_quantized_distance_computations += nq * n_live
+        pre = np.argsort(qc, axis=1, kind="stable")
+        amb = amb | _ambiguous_routing(
+            np.take_along_axis(qc, pre, axis=1),
+            np.take_along_axis(qerr, pre, axis=1),
+            mode, count, margin,
+        )
+        n_amb = int(amb.sum())
+        if n_amb:
+            cent = np.asarray(topo.centroids, np.float32)[live]
+            qc[amb] = _query_centroid_distances(
+                queries[amb], cent, topo.metric
+            )
+            stats.n_distance_computations += n_amb * n_live
+    else:
+        cent = np.asarray(topo.centroids, np.float32)[live]
+        qc = _query_centroid_distances(queries, cent, topo.metric)
+        stats.n_distance_computations += nq * n_live
+    # [Q, n_live] positions into `live`, nearest shard first
+    order = np.argsort(qc, axis=1, kind="stable")
+    if mode == "fixed":
+        return order[:, :min(count, n_live)]
+    # adaptive: probe every shard whose centroid distance is within
+    # `margin` of the query's nearest (d <= d1 + (margin-1)·|d1|, which is
+    # margin·d1 for the non-negative squared-L2 case and degrades
+    # gracefully for negated inner products); distances are sorted, so the
+    # kept set is a per-query prefix and -1 marks each query's unused
+    # probe slots
+    sd = np.take_along_axis(qc, order, axis=1)
+    d1 = sd[:, :1]
+    keep = sd <= d1 + (margin - 1.0) * np.abs(d1)
+    keep[:, 0] = True  # the nearest shard is always probed
+    probes = np.where(keep, order, -1)
+    return probes[:, : int(keep.sum(axis=1).max())]
 
 
 def rerank_shard_pools(

@@ -40,7 +40,7 @@ from repro.serving.policy import AdaptiveWindow, FixedWindow, SLOPolicy
 from repro.serving.queue import (MicroBatcher, PendingRequest, RequestQueue,
                                  ServerOverloadedError)
 from repro.serving.stats import ServerStats
-from repro.telemetry import (SignatureGuard, collect_stages, current_tracer,
+from repro.telemetry import (NULL_SPAN, SignatureGuard, current_tracer,
                              install_compile_listener)
 
 # sentinel: "use the server-level default" for per-request options
@@ -158,10 +158,12 @@ class AnnServer:
     groups a flushed batch by the ``(nprobe, dtype)`` pair so mixed
     batches still make one engine call per distinct option set.
 
-    ``tracer`` (default: the process-wide :func:`current_tracer`) draws
-    every request as an async lane on the timeline — submit → queue wait
-    → batch assembly → engine → rerank → future resolution, keyed by
-    request id.  The tracer's clock **must share the server's** (pass
+    ``tracer`` (default: the process-wide :func:`current_tracer`, looked
+    up anew at every flush, so a tracer installed after start-up is seen)
+    draws every request as an async lane on the timeline — submit → queue
+    wait → batch assembly → engine → future resolution, keyed by request
+    id — and each flush as a ``serve.flush`` span on the event loop's
+    thread.  The tracer's clock **must share the server's** (pass
     ``Tracer(clock=time.monotonic)`` for the default server clock):
     request timestamps are taken with ``self.clock`` and emitted into the
     tracer's time base verbatim.  With the default no-op tracer the hot
@@ -190,7 +192,7 @@ class AnnServer:
             )
         self.stats = ServerStats()
         self.clock = clock
-        self.tracer = current_tracer() if tracer is None else tracer
+        self._tracer = tracer
         self._scope = next(_SERVER_SEQ)
         self._rids = itertools.count()
         self._sig_guard = SignatureGuard()
@@ -211,6 +213,11 @@ class AnnServer:
         self._dim = int(np.asarray(self.topology.data).shape[1])
         self.topology_generation = 0
         self.stats.registry.gauge(*_GENERATION_METRIC).set(0)
+
+    @property
+    def tracer(self):
+        """The tracer given at construction, else the process-wide one."""
+        return current_tracer() if self._tracer is None else self._tracer
 
     # ---- live topology swap ---------------------------------------------
 
@@ -389,66 +396,64 @@ class AnnServer:
             batch = await self._queue.next_batch()
             if batch is None:
                 return
-            t_flush = self.clock()
-            for req in batch:
-                req.t_flush = t_flush
-            self._inflight = batch  # visible to the death handler
-            try:
-                if self.config.run_in_executor:
-                    outs = await loop.run_in_executor(
-                        None, self._execute, batch
-                    )
-                else:
-                    outs = self._execute(batch)
-            except Exception as e:  # engine failure: fail this batch only
-                self.stats.record_failed(len(batch))
-                for req in batch:
-                    if not req.future.done():
-                        req.future.set_exception(e)
-                self._inflight = []
-                continue
-            now = self.clock()
-            traced = self.tracer.enabled
-            for req, (ids, group_size, t_eng0, t_eng1, rerank_s) in zip(
-                    batch, outs):
-                if req.future.done():  # submitter gave up (cancelled)
-                    continue
-                self.stats.record_completion(
-                    req.t_submit, now,
-                    queue_wait_s=req.t_flush - req.t_submit,
-                    engine_s=t_eng1 - t_eng0,
-                )
-                req.future.set_result(QueryResult(
-                    ids=ids, latency_s=max(now - req.t_submit, 0.0),
-                    batch_size=group_size,
-                ))
-                if traced:
-                    self._emit_request_trace(req, now, t_eng0, t_eng1,
-                                             rerank_s)
-            self._inflight = []
+            tr = self.tracer
+            with tr.span("serve.flush") if tr.enabled else NULL_SPAN:
+                await self._flush(loop, tr, batch)
 
-    def _emit_request_trace(self, req: PendingRequest, t_done: float,
-                            t_eng0: float, t_eng1: float,
-                            rerank_s: float) -> None:
+    async def _flush(self, loop, tr, batch: list[PendingRequest]) -> None:
+        """Hand one flushed batch to the engine and resolve its futures."""
+        t_flush = self.clock()
+        for req in batch:
+            req.t_flush = t_flush
+        self._inflight = batch  # visible to the death handler
+        try:
+            if self.config.run_in_executor:
+                outs = await loop.run_in_executor(None, self._execute, batch)
+            else:
+                outs = self._execute(batch)
+        except Exception as e:  # engine failure: fail this batch only
+            self.stats.record_failed(len(batch))
+            for req in batch:
+                if not req.future.done():
+                    req.future.set_exception(e)
+            self._inflight = []
+            return
+        now = self.clock()
+        traced = tr.enabled
+        for req, (ids, group_size, t_eng0, t_eng1) in zip(batch, outs):
+            if req.future.done():  # submitter gave up (cancelled)
+                continue
+            self.stats.record_completion(
+                req.t_submit, now,
+                queue_wait_s=req.t_flush - req.t_submit,
+                engine_s=t_eng1 - t_eng0,
+            )
+            req.future.set_result(QueryResult(
+                ids=ids, latency_s=max(now - req.t_submit, 0.0),
+                batch_size=group_size,
+            ))
+            if traced:
+                self._emit_request_trace(tr, req, now, t_eng0, t_eng1)
+        self._inflight = []
+
+    def _emit_request_trace(self, tr, req: PendingRequest, t_done: float,
+                            t_eng0: float, t_eng1: float) -> None:
         """One request's life as an async lane: the ``serve.request``
         parent plus contiguous child phases that tile it end to end
-        (queue wait → batch assembly → engine → rerank → resolution), all
-        keyed by the request id so overlapping requests render as
-        separate lanes.  Emitted after resolution; timestamps are the
-        server-clock readings the worker already took, so tracing adds no
-        clock reads to the hot path."""
-        tr = self.tracer
+        (queue wait → batch assembly → engine → resolution), all keyed by
+        the request id so overlapping requests render as separate lanes.
+        Emitted after resolution; timestamps are the server-clock readings
+        the worker already took, so tracing adds no clock reads to the hot
+        path.  The engine phase's inner stages (``split.*``, ``beam.*``)
+        are spans of the executor thread."""
         aid = f"srv{self._scope}:req{req.rid}"
-        t_rr0 = max(t_eng1 - rerank_s, t_eng0)
         tr.async_complete("serve.request", aid, req.t_submit, t_done,
                           cat="serving", track="requests", rid=req.rid)
         tr.async_complete("serve.queue_wait", aid, req.t_submit,
                           req.t_flush, cat="serving", track="requests")
         tr.async_complete("serve.batch", aid, req.t_flush, t_eng0,
                           cat="serving", track="requests")
-        tr.async_complete("serve.engine", aid, t_eng0, t_rr0,
-                          cat="serving", track="requests")
-        tr.async_complete("serve.rerank", aid, t_rr0, t_eng1,
+        tr.async_complete("serve.engine", aid, t_eng0, t_eng1,
                           cat="serving", track="requests")
         tr.async_complete("serve.resolve", aid, t_eng1, t_done,
                           cat="serving", track="requests")
@@ -499,8 +504,8 @@ class AnnServer:
         real work, so results are unaffected and stats can be rescaled).
         Each request's slot carries its engine call's ``(t0, t1)`` window
         (server-clock readings — cross-thread safe with the monotonic
-        default) and the exact-rerank share of it, for the latency
-        decomposition and the per-request trace lanes.
+        default), for the latency decomposition and the per-request trace
+        lanes.
         """
         cfg = self.config
         clk = self.clock
@@ -535,15 +540,13 @@ class AnnServer:
                                         backend=cfg.backend, batch=b,
                                         dtype=dtype)
             t0 = clk()
-            with collect_stages() as stages:
-                ids, st = search(
-                    topo, queries, cfg.k, backend=cfg.backend,
-                    width=cfg.width, n_entries=cfg.n_entries, nprobe=nprobe,
-                    dtype=dtype, rerank=cfg.rerank,
-                )
+            ids, st = search(
+                topo, queries, cfg.k, backend=cfg.backend,
+                width=cfg.width, n_entries=cfg.n_entries, nprobe=nprobe,
+                dtype=dtype, rerank=cfg.rerank,
+            )
             t1 = clk()
             self.stats.observe_batch(m, b, st, t1 - t0)
-            rerank_s = stages.get("search.rerank", 0.0)
             for j, i in enumerate(idxs):
-                out[i] = (ids[j], m, t0, t1, rerank_s)
+                out[i] = (ids[j], m, t0, t1)
         return out  # type: ignore[return-value]

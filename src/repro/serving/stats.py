@@ -79,6 +79,14 @@ class ServerStats:
             "serving_engine_time_seconds_total",
             "engine service wall time, summed over batches",
         )
+        self._c_beam_calls = reg.counter(
+            "serving_beam_calls_total",
+            "beam searches dispatched (one per probed shard when routed)",
+        )
+        self._c_h2d = reg.counter(
+            "serving_h2d_bytes_total",
+            "bytes of host arrays the beams uploaded to the device",
+        )
         cap = int(latency_cap)
         self._h_latency = reg.histogram(
             "serving_request_latency_seconds",
@@ -200,6 +208,8 @@ class ServerStats:
         self._c_quant.inc(stats.n_quantized_distance_computations * scale)
         self._c_rerank.inc(stats.n_rerank_distance_computations * scale)
         self._c_engine_s.inc(elapsed_s)
+        self._c_beam_calls.inc(stats.n_beam_calls)
+        self._c_h2d.inc(stats.h2d_bytes)
 
     # ---- reading --------------------------------------------------------
 
@@ -264,4 +274,9 @@ class ServerStats:
             "engine_time_ms_per_batch":
                 (self.batch_time_s / self.n_batches * 1e3)
                 if self.n_batches else 0.0,
+            # unscaled by padding: the calls and bytes really happen
+            "beam_calls_per_batch":
+                self._c_beam_calls.value / max(self.n_batches, 1),
+            "h2d_bytes_per_batch":
+                self._c_h2d.value / max(self.n_batches, 1),
         }

@@ -8,7 +8,8 @@ preemption, served QPS at recall parity); this package is how a run
 * :mod:`repro.telemetry.trace` — hierarchical span tracer with
   Chrome/Perfetto trace-event export, thread-safe, fake-clock
   deterministic.  A whole fleet build and a whole serving session render
-  on one timeline.
+  on one timeline.  Its ``"profiler"`` sink puts the same spans into a
+  JAX profiler trace instead, beside the device's events.
 * :mod:`repro.telemetry.metrics` — counters / gauges / reservoir
   histograms with Prometheus text exposition and a JSON snapshot;
   ``ServerStats``, the fleet executor and the build drivers feed it.
@@ -30,10 +31,9 @@ from repro.telemetry.metrics import (DEFAULT_BUCKETS, Counter, Gauge,
                                      Histogram, MetricsRegistry,
                                      current_registry, parse_prometheus,
                                      set_registry, use_registry)
-from repro.telemetry.trace import (NULL_TRACER, ManualClock, NullTracer,
-                                   Span, Tracer, collect_stages,
-                                   current_tracer, record_stage, set_tracer,
-                                   stage_active, use_tracer)
+from repro.telemetry.trace import (NULL_SPAN, NULL_TRACER, ManualClock,
+                                   NullTracer, Span, Tracer, current_tracer,
+                                   set_tracer, use_tracer)
 from repro.telemetry.validate import (check_durability_trace,
                                       check_fleet_trace,
                                       check_serving_trace,
@@ -41,11 +41,10 @@ from repro.telemetry.validate import (check_durability_trace,
 
 __all__ = [
     "Counter", "DEFAULT_BUCKETS", "Gauge", "Histogram", "ManualClock",
-    "MetricsRegistry", "NULL_TRACER", "NullTracer", "SignatureGuard",
-    "Span", "Tracer", "check_durability_trace", "check_fleet_trace",
-    "check_serving_trace",
-    "collect_stages", "current_registry", "current_tracer",
-    "install_compile_listener", "parse_prometheus", "record_stage",
-    "set_registry", "set_tracer", "stage_active", "use_registry",
-    "use_tracer", "validate_chrome_trace",
+    "MetricsRegistry", "NULL_SPAN", "NULL_TRACER", "NullTracer",
+    "SignatureGuard", "Span", "Tracer", "check_durability_trace",
+    "check_fleet_trace", "check_serving_trace", "current_registry",
+    "current_tracer", "install_compile_listener", "parse_prometheus",
+    "set_registry", "set_tracer", "use_registry", "use_tracer",
+    "validate_chrome_trace",
 ]

@@ -26,16 +26,19 @@ default (``thread/<name>``).  Nesting in the Chrome export is by time
 containment per track, exactly how the viewers render it; explicit
 ``parent`` span ids are additionally recorded in ``args`` for validators.
 
-Two extra surfaces the span stack can't express:
+One extra surface the span stack can't express:
 
 * :meth:`Tracer.async_complete` — Chrome *async* (``ph: b/e``) event
   pairs keyed by an id, for overlapping request flows: every served
   request gets its own ``serve.request`` lane keyed by request id, with
-  queue/batch/engine/rerank child phases under it.
-* :func:`record_stage` / :func:`collect_stages` — a thread-local stage
-  accumulator that lets a deep callee (the exact re-rank epilogue inside
-  a backend driver) report a duration to whoever is timing the enclosing
-  call, without threading a tracer through every signature.
+  queue/batch/engine/resolve child phases under it.
+
+Two sinks.  ``Tracer()`` (``sink="chrome"``) keeps the events in memory
+for the Chrome export above.  ``Tracer(sink="profiler")`` keeps nothing:
+each :meth:`Tracer.span` enters a ``jax.profiler.TraceAnnotation`` of the
+same name, so while a JAX profiler session runs the span lands on the
+profiler's host plane, on the clock the device events are aligned to,
+and a gap in device activity is named by the program span around it.
 """
 
 from __future__ import annotations
@@ -47,9 +50,8 @@ import time
 from typing import Any, Callable
 
 __all__ = [
-    "ManualClock", "NullTracer", "NULL_TRACER", "Span", "Tracer",
-    "collect_stages", "current_tracer", "record_stage", "set_tracer",
-    "use_tracer",
+    "ManualClock", "NullTracer", "NULL_SPAN", "NULL_TRACER", "Span",
+    "Tracer", "current_tracer", "set_tracer", "use_tracer",
 ]
 
 
@@ -131,7 +133,7 @@ class _NullSpan:
         return self
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 class NullTracer:
@@ -145,7 +147,7 @@ class NullTracer:
         return 0.0
 
     def span(self, *a: Any, **kw: Any) -> _NullSpan:
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def instant(self, *a: Any, **kw: Any) -> None:
         return None
@@ -163,6 +165,30 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
+class _ProfilerSpan:
+    """A span of the ``"profiler"`` sink: one ``TraceAnnotation`` under the
+    span's bare name (its arguments are dropped — the profiler would encode
+    them into the event name)."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+
+    def set(self, **args: Any) -> "_ProfilerSpan":
+        return self
+
+
+SINKS = ("chrome", "profiler")
+
+
 class Tracer:
     """Thread-safe span/event recorder exporting Chrome trace-event JSON.
 
@@ -172,12 +198,25 @@ class Tracer:
     reads ``tracer.now()``).  ``max_events`` bounds memory on long runs —
     past it new events are dropped and counted (``otherData.dropped`` in
     the export), never blocking the traced workload.
+
+    ``sink="profiler"`` sends spans to the JAX profiler instead (see the
+    module docstring): nothing is kept in memory, :meth:`instant`,
+    :meth:`complete` and :meth:`async_complete` record nothing, and the
+    export is empty.
     """
 
     enabled = True
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter, *,
-                 process: str = "repro", max_events: int = 2_000_000):
+                 process: str = "repro", max_events: int = 2_000_000,
+                 sink: str = "chrome"):
+        if sink not in SINKS:
+            raise ValueError(f"sink must be one of {SINKS}, got {sink!r}")
+        self._annotation = None
+        if sink == "profiler":
+            from jax.profiler import TraceAnnotation  # deferred: jax-free
+
+            self._annotation = TraceAnnotation
         self._clock = clock
         self.process = process
         self.max_events = int(max_events)
@@ -241,15 +280,19 @@ class Tracer:
         return self._clock()
 
     def span(self, name: str, *, track: str | None = None,
-             **args: Any) -> Span:
+             **args: Any) -> Span | _ProfilerSpan:
         """Open a nested span (context manager).  ``track`` pins the
         timeline row; omitted, it inherits the enclosing span's row (or a
         per-thread default)."""
+        if self._annotation is not None:
+            return _ProfilerSpan(self._annotation(name))
         return Span(self, name, track, args)
 
     def instant(self, name: str, *, track: str | None = None,
                 **args: Any) -> None:
         """A zero-duration marker (preemption notice, kill signal, ...)."""
+        if self._annotation is not None:
+            return
         track = self._resolve_track(track)
         stack = self._stack()
         parent = stack[-1].sid if stack else -1
@@ -261,6 +304,8 @@ class Tracer:
         """Emit a finished span post-hoc from explicit ``tracer.now()``
         readings — for call sites that can't wrap their body in a
         ``with`` (per-round build telemetry, backoff windows)."""
+        if self._annotation is not None:
+            return
         track = self._resolve_track(track)
         stack = self._stack()
         parent = stack[-1].sid if stack else -1
@@ -275,6 +320,8 @@ class Tracer:
         order — emit the parent phase first, children inside.  This is
         how overlapping per-request lanes render without fighting over
         one synchronous track."""
+        if self._annotation is not None:
+            return
         a = dict(args, cat=cat)
         self._append(name, "b", track, t0, None, a, aid=str(aid))
         self._append(name, "e", track, t1, None, {"cat": cat},
@@ -361,41 +408,3 @@ class use_tracer:
 
     def __exit__(self, *exc) -> None:
         set_tracer(self._prev)
-
-
-# ---- stage accumulation --------------------------------------------------
-
-_stage_tls = threading.local()
-
-
-def record_stage(name: str, seconds: float) -> None:
-    """Report a stage duration to the innermost active
-    :func:`collect_stages` on this thread (no-op when none is active).
-
-    Lets a deep callee — the exact-f32 re-rank epilogue inside a search
-    driver — surface its share of an enclosing timed call without every
-    signature in between growing a telemetry parameter."""
-    sink = getattr(_stage_tls, "sink", None)
-    if sink is not None:
-        sink[name] = sink.get(name, 0.0) + float(seconds)
-
-
-class collect_stages:
-    """``with collect_stages() as stages: ...`` — capture
-    :func:`record_stage` reports made on this thread inside the block.
-    ``stages`` is a plain ``{name: seconds}`` dict."""
-
-    def __enter__(self) -> dict:
-        self._prev = getattr(_stage_tls, "sink", None)
-        self.stages: dict[str, float] = {}
-        _stage_tls.sink = self.stages
-        return self.stages
-
-    def __exit__(self, *exc) -> None:
-        _stage_tls.sink = self._prev
-
-
-def stage_active() -> bool:
-    """True when a :func:`collect_stages` block is open on this thread —
-    lets a callee skip even the clock reads when nobody is listening."""
-    return getattr(_stage_tls, "sink", None) is not None

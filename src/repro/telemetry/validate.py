@@ -12,7 +12,7 @@ fails CI instead of producing a silently unloadable artifact.
 ``check_durability_trace`` are the *semantic* checks: the fleet trace
 must show an injected preemption's kill → backoff → resume lifecycle on
 worker tracks, the serving trace must decompose each sampled request's
-end-to-end latency into its queue/batch/engine/rerank/resolve phases
+end-to-end latency into its queue/batch/engine/resolve phases
 with <5% residual, and the durability trace must show the WAL → crash →
 recover → replay lifecycle the crash-injection bench drives.
 """
@@ -206,7 +206,7 @@ def check_durability_trace(obj, min_crashes: int = 1) -> dict:
 
 #: child phase names of one serve.request lane (emission order)
 SERVING_PHASES = ("serve.queue_wait", "serve.batch", "serve.engine",
-                  "serve.rerank", "serve.resolve")
+                  "serve.resolve")
 
 
 def check_serving_trace(obj, min_coverage: float = 0.95) -> dict:

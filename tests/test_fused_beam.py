@@ -146,6 +146,9 @@ def _assert_search_parity(topo, queries, dtype):
     jids, jstats = search(topo, queries, K, backend="jax", **kw)
     pids, pstats = search(topo, queries, K, backend="pallas", **kw)
     np.testing.assert_array_equal(pids, jids)
+    # every counter but the uploaded bytes, which differ by design: the
+    # pallas engine keeps each store on the device between calls
+    pstats.h2d_bytes = jstats.h2d_bytes = 0
     assert dataclasses.asdict(pstats) == dataclasses.asdict(jstats)
 
 

@@ -1,29 +1,34 @@
 """The telemetry subsystem's contracts: byte-stable span trees under a
 fake clock, a truly free no-op recorder on the serving hot path, the
-Prometheus exposition round-trip, and the two traced end-to-end flows
+Prometheus exposition round-trip, the program's spans in a JAX profiler
+trace beside its device work, and the two traced end-to-end flows
 (serving request decomposition, fleet preemption lifecycle) the smoke
 benches are CI-guarded with."""
 
 import asyncio
 import gc
+import glob
 import json
+import os
 import sys
 import time
 
+import jax
 import numpy as np
 import pytest
 
 from repro.configs.base import IndexConfig
+from repro.core.builder import build_scalegann
 from repro.core.scheduler import RuntimeModel
 from repro.data.synthetic import make_clustered
 from repro.fleet import PreemptionInjector, build_scalegann_fleet
-from repro.search import ShardTopology
+from repro.search import ShardTopology, jax_backend, search
 from repro.serving import AnnServer, ServerStats, ServingConfig
-from repro.telemetry import (NULL_TRACER, ManualClock, MetricsRegistry,
-                             SignatureGuard, Tracer, check_fleet_trace,
-                             check_serving_trace, collect_stages,
-                             current_tracer, parse_prometheus, record_stage,
-                             stage_active, use_tracer, validate_chrome_trace)
+from repro.telemetry import (NULL_SPAN, NULL_TRACER, ManualClock,
+                             MetricsRegistry, SignatureGuard, Tracer,
+                             check_fleet_trace, check_serving_trace,
+                             current_tracer, parse_prometheus, use_tracer,
+                             validate_chrome_trace)
 
 # ---------------------------------------------------------------------------
 # span tracer: determinism, nesting, export schema
@@ -130,12 +135,19 @@ def test_null_tracer_shares_singletons():
 
 
 def _serving_hot_pattern(tr, n: int) -> None:
-    """The exact gating idiom the serving worker uses: one branch, and the
-    kwargs-building call is never reached when disabled."""
+    """The exact gating idioms the program uses: one branch, and the
+    kwargs-building call is never reached when disabled; the span sites
+    (``serve.flush``, ``split.*``, ``beam.*``, ``cagra.*``, ``merge.*``,
+    ``build.gather``) take the shared no-op span instead of calling
+    ``span``."""
     for _ in range(n):
         if tr.enabled:
             tr.instant("serve.retrace_risk", track="jit",
                        backend="jax", batch=8)
+        with tr.span("serve.flush") if tr.enabled else NULL_SPAN:
+            with tr.span("split.beam") if tr.enabled else NULL_SPAN:
+                with tr.span("beam.stage") if tr.enabled else NULL_SPAN:
+                    pass
 
 
 def test_null_tracer_hot_path_zero_allocations():
@@ -149,16 +161,197 @@ def test_null_tracer_hot_path_zero_allocations():
     assert after - before <= 2
 
 
-def test_stage_accumulator_thread_local_and_gated():
-    assert not stage_active()
-    record_stage("search.rerank", 1.0)  # nobody listening: dropped
-    with collect_stages() as stages:
-        assert stage_active()
-        record_stage("search.rerank", 0.25)
-        record_stage("search.rerank", 0.5)
-        record_stage("other", 1.5)
-    assert stages == {"search.rerank": 0.75, "other": 1.5}
-    assert not stage_active()
+def test_null_tracer_creates_no_trace_annotation(monkeypatch, tiny_routed):
+    """The program's span sites never reach the profiler while the no-op
+    recorder is installed; a profiler-sink tracer reaches it once per
+    span."""
+    made = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    q = tiny_routed.data[:8]
+    assert current_tracer() is NULL_TRACER
+    search(tiny_routed, q, 5, backend="jax", width=32, nprobe="auto",
+           dtype="uint8")
+    assert made == []
+    with use_tracer(Tracer(sink="profiler")):
+        search(tiny_routed, q, 5, backend="jax", width=32, nprobe="auto",
+               dtype="uint8")
+    assert made[0] == "search.engine" and "split.route" in made
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink: the program's spans on the device trace's clock
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_build():
+    ds = make_clustered(800, 16, n_queries=16, seed=3)
+    cfg = IndexConfig(n_clusters=4, degree=8, build_degree=16,
+                      block_size=512)
+    return ds, cfg, build_scalegann(ds.data, cfg, algo="cagra")
+
+
+@pytest.fixture(scope="module")
+def tiny_routed(tiny_build):
+    ds, _, res = tiny_build
+    return res.shard_topology(ds.data)
+
+
+def _profiled(log_dir, fn):
+    """Run ``fn()`` under a JAX profiler trace with a profiler-sink tracer
+    installed; returns its result and the trace's host events as
+    ``(name, start_ns, end_ns, line)``."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with use_tracer(Tracer(sink="profiler")):
+        jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+        try:
+            out = fn()
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                events += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            (plane.name, i)) for e in line.events]
+    return out, events
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def _inside(inner, outer) -> bool:
+    """Nested on one thread's line, on the profiler's one clock."""
+    return (inner[3] == outer[3] and outer[1] <= inner[1]
+            and inner[2] <= outer[2])
+
+
+def _children(events, outer, name):
+    return [e for e in _named(events, name) if _inside(e, outer)]
+
+
+def _assert_beam_calls(events, outer, n):
+    """``n`` beams inside ``outer``, each staged then run on the device,
+    the device span holding the host event of its ``_batch_beam``
+    execution."""
+    stages = _children(events, outer, "beam.stage")
+    devices = _children(events, outer, "beam.device")
+    assert len(stages) == len(devices) == n
+    for st, dev in zip(sorted(stages, key=lambda e: e[1]),
+                       sorted(devices, key=lambda e: e[1])):
+        assert st[2] <= dev[1]
+        assert _children(events, dev, "PjitFunction(_batch_beam)")
+
+
+def test_profiler_sink_routed_search_spans(tmp_path, tiny_routed):
+    q = tiny_routed.data[:12]
+    (_, stats), events = _profiled(tmp_path, lambda: search(
+        tiny_routed, q, 5, backend="jax", width=32, nprobe="auto",
+        dtype="uint8"))
+    (engine,) = _named(events, "search.engine")  # the bare name: no args
+    for name in ("split.route", "split.pool", "split.rerank"):
+        assert len(_children(events, engine, name)) == 1, name
+    beams = _children(events, engine, "split.beam")
+    assert len(beams) == stats.n_beam_calls >= 1
+    for b in beams:
+        _assert_beam_calls(events, b, 1)
+    _assert_beam_calls(events, engine, stats.n_beam_calls)
+    order = [_named(events, n)[0][1] for n in
+             ("split.route", "split.beam", "split.pool", "split.rerank")]
+    assert order == sorted(order)
+
+
+def test_profiler_sink_merged_search_spans(tmp_path, tiny_build):
+    ds, _, res = tiny_build
+    (_, stats), events = _profiled(tmp_path, lambda: search(
+        res.index, ds.queries, 5, backend="jax", data=ds.data, width=32))
+    (engine,) = _named(events, "search.engine")
+    assert stats.n_beam_calls == 1
+    _assert_beam_calls(events, engine, 1)
+    assert not _named(events, "split.beam")
+
+
+def test_profiler_sink_build_spans(tmp_path, tiny_build):
+    ds, cfg, _ = tiny_build
+    res, events = _profiled(tmp_path, lambda: build_scalegann(
+        ds.data, cfg, algo="cagra"))
+    n = len(res.shards)
+    assert len(_named(events, "build.partition")) == 1
+    (shards,) = _named(events, "build.shards")
+    assert len(_children(events, shards, "build.gather")) == n
+    built = _children(events, shards, "build.shard")
+    assert len(built) == n
+    for b in built:
+        for name in ("cagra.knn", "cagra.detour", "cagra.prune"):
+            assert len(_children(events, b, name)) == 1, name
+    (merge,) = _named(events, "build.merge")
+    for name in ("merge.edges", "merge.sort", "merge.distances",
+                 "merge.cap"):
+        assert len(_children(events, merge, name)) == 1, name
+
+
+def test_profiler_sink_records_nothing_in_memory():
+    tr = Tracer(sink="profiler")
+    with tr.span("a", track="x", n=1) as sp:
+        assert sp.set(n=2) is sp
+    tr.instant("b", n=1)
+    tr.complete("c", 0.0, 1.0)
+    tr.async_complete("d", "id", 0.0, 1.0)
+    assert tr.to_chrome()["traceEvents"][1:] == []
+    with pytest.raises(ValueError):
+        Tracer(sink="file")
+
+
+# ---------------------------------------------------------------------------
+# engine-call counters: beams dispatched and bytes uploaded
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("nprobe,dtype", [(None, "f32"), (1, "f32"),
+                                          ("auto", "uint8")])
+def test_split_counters_match_probed_shards_and_uploads(
+        monkeypatch, tiny_routed, nprobe, dtype):
+    uploaded = []
+    beam = jax_backend._batch_beam
+
+    def spy(x, g, e, q, *rest):
+        uploaded.append(x.nbytes + g.nbytes + e.nbytes + q.nbytes)
+        return beam(x, g, e, q, *rest)
+
+    monkeypatch.setattr(jax_backend, "_batch_beam", spy)
+    tracer = Tracer(ManualClock())
+    q = tiny_routed.data[::50][:12]
+    with use_tracer(tracer):
+        _, stats = search(tiny_routed, q, 5, backend="jax", width=32,
+                          nprobe=nprobe, dtype=dtype)
+    probed = [e for e in tracer.to_chrome()["traceEvents"]
+              if e["name"] == "split.beam"]
+    assert stats.n_beam_calls == len(probed) == len(uploaded)
+    if nprobe is None:
+        assert stats.n_beam_calls == len(tiny_routed.shard_ids)
+    assert stats.h2d_bytes == sum(uploaded)
+
+
+def test_merged_h2d_bytes_are_the_uploaded_shapes(tiny_build):
+    ds, _, res = tiny_build
+    n, d = ds.data.shape
+    _, stats = search(res.index, ds.queries, 5, backend="jax", data=ds.data,
+                      width=32, n_entries=16)
+    entries = len(res.index.entry_points(16))
+    assert stats.n_beam_calls == 1
+    assert stats.h2d_bytes == (n * d * 4 + res.index.graph.size * 4
+                               + entries * 4 + len(ds.queries) * d * 4)
 
 
 # ---------------------------------------------------------------------------
@@ -360,6 +553,64 @@ def test_traced_serving_counts_post_warm_signatures(ring):
     asyncio.run(main())
     names = {e["name"] for e in tracer.to_chrome()["traceEvents"]}
     assert "serve.retrace_risk" in names
+
+
+def test_server_sees_a_tracer_installed_after_start(ring):
+    """Without ``tracer=`` the server looks the process-wide tracer up at
+    every flush: one installed mid-run gets each flush as a ``serve.flush``
+    span on the event loop's thread, holding the engine's spans of the
+    executor thread in time; an explicit ``tracer=`` stays pinned."""
+    data, topo = ring
+    late = Tracer(clock=time.monotonic)
+    pinned = Tracer(clock=time.monotonic)
+
+    async def main():
+        sc = ServingConfig(backend="numpy", k=5, width=64, max_batch=4,
+                           max_wait_ms=2.0)
+        async with AnnServer(topo, config=sc) as srv, \
+                AnnServer(topo, config=sc, tracer=pinned) as fixed:
+            # both warmed and serving before the tracer is installed
+            await srv.submit(data[0])
+            await fixed.submit(data[0])
+            with use_tracer(late):
+                await srv.submit(data[1])
+                await fixed.submit(data[2])
+            return srv.stats.snapshot()
+
+    snap = asyncio.run(main())
+    ev = [e for e in late.to_chrome()["traceEvents"] if e["ph"] == "X"]
+    (f,) = [e for e in ev if e["name"] == "serve.flush"]
+    # the search.engine span of each server's call (both follow the
+    # process-wide tracer); the late-traced server's lies inside its flush
+    engines = [e for e in ev if e["name"] == "search.engine"]
+    assert len(engines) == 2
+    assert any(f["tid"] != g["tid"]  # loop thread vs executor thread
+               and f["ts"] <= g["ts"]
+               and g["ts"] + g["dur"] <= f["ts"] + f["dur"]
+               for g in engines)
+    pinned_names = {e["name"] for e in pinned.to_chrome()["traceEvents"]}
+    assert "serve.flush" in pinned_names and "serve.request" in pinned_names
+    assert snap["n_batches"] == 2
+    assert snap["beam_calls_per_batch"] == 1.0  # one shard, numpy beam
+    assert snap["h2d_bytes_per_batch"] == 0.0  # the numpy beam uploads none
+
+
+def test_server_stats_expose_beam_calls_and_uploads():
+    from repro.search import SearchStats
+
+    st = ServerStats()
+    assert st.snapshot()["beam_calls_per_batch"] == 0.0
+    st.observe_batch(3, 4, SearchStats(n_beam_calls=5, h2d_bytes=1000),
+                     0.01)
+    st.observe_batch(4, 4, SearchStats(n_beam_calls=3, h2d_bytes=3000),
+                     0.01)
+    snap = st.snapshot()
+    # padding scales neither: the calls and the bytes really happen
+    assert snap["beam_calls_per_batch"] == 4.0
+    assert snap["h2d_bytes_per_batch"] == 2000.0
+    parsed = parse_prometheus(st.to_prometheus())
+    assert parsed[("serving_beam_calls_total", frozenset())] == 8
+    assert parsed[("serving_h2d_bytes_total", frozenset())] == 4000
 
 
 # ---------------------------------------------------------------------------
