@@ -202,6 +202,41 @@ def _dedup_refill_rows(
     return out.astype(graph.dtype)
 
 
+@jax.jit
+def _gather_block(x: jax.Array, nbrs: jax.Array, dists: jax.Array,
+                  rows: jax.Array):
+    """One detour block's inputs, gathered on the device from the shard's
+    resident arrays: ``x[rows]`` [C, D], ``x[nbrs[rows]]`` [C, L, D] and
+    ``dists[rows]`` [C, L] — exact copies, the values a host gather of the
+    same rows would upload."""
+    return x[rows], x[nbrs[rows]], dists[rows]
+
+
+def _count_detours(x: np.ndarray, safe_nbrs: np.ndarray, dists: np.ndarray,
+                   metric: str, node_block: int) -> np.ndarray:
+    """Detour counts ``[n, L]`` (int64) of every row of one shard.
+
+    The shard's vectors, clamped neighbour ids and kNN distances are
+    uploaded once; each block then uploads only its row ids and reads back
+    its counts.  The gather stays a jitted function of its own and is not
+    fused into :func:`_detour_counts`: that way the executable that counts
+    is the one a host gather fed, on the same values, so the counts are
+    bit-identical to it (a fused gather would let XLA reorder the sums of
+    squares)."""
+    n, L = safe_nbrs.shape
+    counts = np.empty((n, L), np.int64)
+    x_d, nbrs_d, dists_d = (jnp.asarray(a) for a in (x, safe_nbrs, dists))
+    nb = min(node_block, n)
+    for s in range(0, n, nb):
+        e = min(s + nb, n)
+        # fixed block shape (the last block repeats row n-1, dropped
+        # below): one compile per shard size, not one per remainder
+        rows = np.minimum(np.arange(s, s + nb, dtype=np.int32), n - 1)
+        c = _detour_counts(*_gather_block(x_d, nbrs_d, dists_d, rows), metric)
+        counts[s:e] = np.asarray(c)[: e - s]
+    return counts
+
+
 def optimize_graph(
     vectors: np.ndarray,
     nbrs: np.ndarray,
@@ -215,39 +250,30 @@ def optimize_graph(
     """Prune the degree-L kNN graph to degree R: keep the R/2 forward edges
     with the fewest detours, then fill with reverse edges (CAGRA §4.2).
 
+    The detour counts run in blocks of ``node_block`` rows over the shard's
+    vectors, neighbour ids and distances, held on the device for the whole
+    shard (:func:`_count_detours`); only row ids go up and counts come back
+    per block.
+
     ``reference=True`` runs the original per-node python loops for the
     reverse-edge fill and the row dedup/refill instead of the vectorized
     segment-scatter / sort-dedup paths — same output bit for bit
     (parity-tested), kept as the ``bench_build.py`` seed-loop baseline.
+    Both share the detour counts.
 
     Under an enabled tracer, ``cagra.detour`` spans the detour counts
-    (host gathers, uploads, device blocks, reads back) and ``cagra.prune``
-    the host pruning that follows.
+    (per-shard uploads, device gathers and blocks, reads back) and
+    ``cagra.prune`` the host pruning that follows.
     """
     n, L = nbrs.shape
-    x = vectors.astype(np.float32)
-    n_dist = 0
-    counts = np.empty((n, L), np.int64)
-    safe_nbrs = np.maximum(nbrs, 0)
-    nb = min(node_block, n)
     tr = current_tracer()
     with tr.span("cagra.detour") if tr.enabled else NULL_SPAN:
-        for s in range(0, n, nb):
-            e = min(s + nb, n)
-            # fixed block shape (the last block repeats row n-1, dropped
-            # below): one compile per shard size, not one per remainder
-            rows = np.minimum(np.arange(s, s + nb), n - 1)
-            c = _detour_counts(
-                jnp.asarray(x[rows]),
-                jnp.asarray(x[safe_nbrs[rows]]),
-                jnp.asarray(dists[rows]),
-                metric,
-            )
-            counts[s:e] = np.asarray(c)[: e - s]
-            n_dist += (e - s) * L * L
+        counts = _count_detours(vectors.astype(np.float32),
+                                np.maximum(nbrs, 0), dists, metric,
+                                node_block)
     with tr.span("cagra.prune") if tr.enabled else NULL_SPAN:
         graph = _prune(nbrs, counts, R, reference)
-    return graph, n_dist
+    return graph, n * L * L
 
 
 def _prune(nbrs: np.ndarray, counts: np.ndarray, R: int,
