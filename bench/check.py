@@ -10,14 +10,23 @@ limit:
 - ``invalid``: answers that are not ``k`` distinct ids of base vectors.
   Limit 0.
 - ``order_gap``: the widest amount by which a returned id's exact distance
-  exceeds that of the id returned after it, as a share of the query's true
-  ``k``-th nearest distance.  The configuration states that answers come in
-  ascending exact distance; a lower precision than the one it states breaks
-  that order on near ties.  Limit from the configuration's ``checks``.
+  exceeds that of the id returned after it, over a scale of the query's.
+  The configuration states that answers come in ascending exact distance; a
+  lower precision than the one it states breaks that order on near ties.
+  Limit from the configuration's ``checks``.
 - ``recall_miss``: 1 − recall@k over all judged answers, where an id counts
   when its exact distance is at most the true ``k``-th nearest distance (the
   big-ann-benchmarks definition, ties included).  Limit 1 − the
   configuration's stated recall target.
+
+Distances and scales follow the configuration's ``metric``:
+
+- ``"l2"``: the distance is squared L2, and the scale of ``order_gap`` the
+  query's true ``k``-th nearest distance.
+- ``"ip"``: the distance is ``−q·x``, which can be negative, so it is no
+  scale.  ``order_gap``'s scale is ``‖q‖·‖x_(k)‖``, with ``x_(k)`` the
+  query's true ``k``-th nearest: positive, and a bound on ``|q·x|`` there,
+  so that float32 rounding of the dots reads in units of ``eps·√D``.
 
 A run that builds also compares the graphs it built:
 
@@ -25,8 +34,9 @@ A run that builds also compares the graphs it built:
   other row of its shard, since the exact kNN lists it first and detour
   pruning keeps it first.  The widest amount by which its exact distance
   exceeds that of the reference's nearest (:func:`reference.nearest_other`),
-  as a share of the latter, over every row of every shard; infinite for a
-  row whose entry is missing or not in its shard.  A kNN computed below the
+  over every row of every shard; infinite for a row whose entry is missing
+  or not in its shard.  The excess is taken over the nearest's distance
+  (``"l2"``), or over ``‖x_i‖·‖x_nn‖`` (``"ip"``).  A kNN computed below the
   configuration's stated precision picks a near tie on some rows.
 
 Exact distances of returned ids are recomputed in float64 on the host.
@@ -36,12 +46,14 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.reference import nearest_other, sq_dist64
+from bench.reference import dist64, nearest_other, norms64
+
+TINY = np.finfo(np.float64).tiny
 
 
 def compare(data: np.ndarray, queries: np.ndarray, rows: np.ndarray,
             ids: np.ndarray, gt_ids: np.ndarray, n_unanswered: int,
-            limits: dict, k: int) -> tuple[dict, float]:
+            limits: dict, k: int, metric: str = "l2") -> tuple[dict, float]:
     """Judge answers ``ids [M, k]`` to ``queries[rows]``.
 
     ``gt_ids [P, k]`` are the reference's nearest ids for every query of
@@ -59,15 +71,21 @@ def compare(data: np.ndarray, queries: np.ndarray, rows: np.ndarray,
         ok &= ((ids >= 0) & (ids < n)).all(axis=1)
         srt = np.sort(ids, axis=1)
         ok &= ~(srt[:, 1:] == srt[:, :-1]).any(axis=1)
-    kth = sq_dist64(data, queries, gt_ids[:, :k]).max(axis=1)  # [P]
+    gt_d = dist64(data, queries, gt_ids[:, :k], metric)  # [P, k]
+    kth = gt_d.max(axis=1)  # [P]
     hits = 0
     gap = 0.0
     if ok.any():
         r = rows[ok]
-        d = sq_dist64(data, queries[r], ids[ok])  # [M', k]
-        ref = np.maximum(kth[r], np.finfo(np.float64).tiny)[:, None]
+        d = dist64(data, queries[r], ids[ok], metric)  # [M', k]
         hits = int((d <= kth[r][:, None]).sum())
         if k > 1:
+            if metric == "ip":
+                far = gt_ids[r, gt_d[r].argmax(axis=1)]  # x_(k) of each
+                scale = norms64(queries[r]) * norms64(data[far])
+            else:
+                scale = kth[r]
+            ref = np.maximum(scale, TINY)[:, None]
             gap = float(max(0.0, ((d[:, :-1] - d[:, 1:]) / ref).max()))
     recall = hits / (m * k) if m else 0.0
     checks = {
@@ -80,7 +98,7 @@ def compare(data: np.ndarray, queries: np.ndarray, rows: np.ndarray,
     return checks, recall
 
 
-def nn_gap(data: np.ndarray, graphs: list) -> float:
+def nn_gap(data: np.ndarray, graphs: list, metric: str = "l2") -> float:
     """Judge ``graphs``, ``[(ids [n], first [n])]`` per shard: the shard's
     rows as base ids and each row's first graph entry as a base id."""
     gap = 0.0
@@ -89,13 +107,15 @@ def nn_gap(data: np.ndarray, graphs: list) -> float:
         first = np.asarray(first, np.int64)
         if len(ids) < 2:
             continue
-        nn = ids[nearest_other(data[ids])]
+        nn = ids[nearest_other(data[ids], metric=metric)]
         if not np.isin(first, ids).all() or (first == ids).any():
             return float("inf")
         q = data[ids]
-        d_got = sq_dist64(data, q, first[:, None])[:, 0]
-        d_ref = sq_dist64(data, q, nn[:, None])[:, 0]
-        ref = np.maximum(d_ref, np.finfo(np.float64).tiny)
+        d_got = dist64(data, q, first[:, None], metric)[:, 0]
+        d_ref = dist64(data, q, nn[:, None], metric)[:, 0]
+        scale = (norms64(q) * norms64(data[nn]) if metric == "ip"
+                 else d_ref)
+        ref = np.maximum(scale, TINY)
         gap = max(gap, float(((d_got - d_ref) / ref).max()))
     return max(gap, 0.0)
 

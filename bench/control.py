@@ -39,17 +39,21 @@ def readings(cell: str, seed: int, precision: str | None = None, *,
     for key, v in (overrides or {}).items():
         cfg = dict(cfg, **{key: v})
     k = cfg["k"]
+    metric = cfg["metric"]
     dep = data.deployment(cfg)
-    pool = data.queries(dep, cfg["assumed"]["query_pool"], seed)
+    pool = data.queries(dep, cfg["assumed"]["query_pool"], seed,
+                        cfg.get("queries"))
     n = wl["params"].get("queries", len(pool))
     prec = precision or CONTROL[cfg["dtype"]]
-    _, gt = reference.exact_knn(dep.data, pool, k)
-    _, ids = reference.exact_knn(dep.data, pool[:n], k, precision=prec)
+    _, gt = reference.exact_knn(dep.data, pool, k, metric=metric)
+    _, ids = reference.exact_knn(dep.data, pool[:n], k, precision=prec,
+                                 metric=metric)
     checks, recall = check.compare(dep.data, pool, np.arange(n), ids, gt, 0,
-                                   cfg["checks"], k)
+                                   cfg["checks"], k, metric)
     if wl["driver"] == "build_steps":
         checks["nn_gap"] = {
-            "value": check.nn_gap(dep.data, _graphs(dep.data, cfg, prec)),
+            "value": check.nn_gap(dep.data, _graphs(dep.data, cfg, prec),
+                                  metric),
             "limit": cfg["checks"]["nn_gap_max"]}
     return {"workload": cell, "seed": seed, "precision": prec,
             "correct": check.passed(checks), "recall_at_10": recall,
@@ -63,8 +67,8 @@ def _graphs(data: np.ndarray, cfg: dict, precision: str) -> list:
     out = []
     for shard in partition(data, IndexConfig(**cfg["index"])).shards:
         ids = np.asarray(shard.ids, np.int64)
-        out.append((ids, ids[reference.nearest_other(data[ids],
-                                                     precision=precision)]))
+        out.append((ids, ids[reference.nearest_other(
+            data[ids], precision=precision, metric=cfg["metric"])]))
     return out
 
 

@@ -9,7 +9,24 @@ must not change when the program does.
 
 The base vectors are fixed by the configuration (``data_seed``), so every run
 of a cell builds and serves the same deployment and does the same work; the
-run's ``--seed`` draws the query pool from the same mixture.
+run's ``--seed`` draws the query pool.
+
+Two keys of a configuration say how its answers are judged and where its
+queries come from:
+
+- ``metric``: ``"l2"`` (squared Euclidean distance) or ``"ip"`` (inner
+  product, larger is closer).  ``bench/reference.py`` and
+  ``bench/check.py`` rank and judge by it.
+- ``queries``, optional.  Absent, the pool is drawn from the base's own
+  mixture, at its weights and spread.  ``{"source": "ood", "n_centres":
+  C_q, "shift": s, "spread": σ_q, "centre_seed": c}`` draws it from another
+  distribution (out-of-distribution queries, as text queries against an
+  image base): ``C_q`` query centres ``(1 − s)·c_b + s·g``, each from a base
+  centre ``c_b`` picked uniformly and an independent ``g ~ N(0, I)``, drawn
+  from ``c`` so that the deployment fixes them; the pool is drawn from those
+  centres with equal weights at spread ``σ_q``, through the base's uint8 map
+  where the dtype is uint8.  A configuration that sets it lists its numbers
+  under ``assumed``.
 """
 
 from __future__ import annotations
@@ -60,13 +77,32 @@ def deployment(cfg: dict) -> Deployment:
                       spread=float(a["spread"]), lo=lo, hi=hi)
 
 
-def queries(dep: Deployment, n: int, seed: int) -> np.ndarray:
-    """``n`` queries from the deployment's mixture, drawn from ``seed``, in
-    the deployment's dtype."""
+def query_centres(dep: Deployment, spec: dict) -> np.ndarray:
+    """The ``[C_q, D]`` float32 centres of an ``"ood"`` query source."""
+    rng = rng_for(spec["centre_seed"])
+    picks = rng.integers(0, len(dep.centers), size=spec["n_centres"])
+    g = rng.normal(size=(spec["n_centres"], dep.centers.shape[1]))
+    s = spec["shift"]
+    return ((1.0 - s) * dep.centers[picks] + s * g).astype(np.float32)
+
+
+def queries(dep: Deployment, n: int, seed: int,
+            source: dict | None = None) -> np.ndarray:
+    """``n`` queries drawn from ``seed``, in the deployment's dtype: from the
+    deployment's mixture, or from the configuration's ``queries`` source
+    (module docstring)."""
     rng = rng_for(seed)
-    assign = rng.choice(len(dep.weights), size=n, p=dep.weights)
-    q = dep.centers[assign] + dep.spread * rng.normal(
-        size=(n, dep.centers.shape[1])).astype(np.float32)
+    if source is None:
+        centers, weights, spread = dep.centers, dep.weights, dep.spread
+    elif source.get("source") == "ood":
+        centers = query_centres(dep, source)
+        weights = np.full(len(centers), 1.0 / len(centers))
+        spread = float(source["spread"])
+    else:
+        raise ValueError(f"unknown query source {source!r}")
+    assign = rng.choice(len(weights), size=n, p=weights)
+    q = centers[assign] + spread * rng.normal(
+        size=(n, centers.shape[1])).astype(np.float32)
     if dep.data.dtype == np.uint8:
         return _to_u8(q, dep.lo, dep.hi)
     return q.astype(np.float32)

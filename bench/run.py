@@ -44,6 +44,7 @@ for _p in (os.path.join(_ROOT, "src"), _ROOT):
         sys.path.insert(0, _p)
 
 from bench import check, common, data, reference  # noqa: E402
+from bench import spans as spansmod  # noqa: E402
 from bench import trace as tracemod  # noqa: E402
 
 
@@ -96,6 +97,7 @@ class Context:
 class _NullProfiler:
     active = False
     summary = None
+    spans = None
     window_s = None
 
     def start(self):
@@ -107,20 +109,27 @@ class _NullProfiler:
 
 class _Profiler:
     """The JAX profiler, without the Python tracer (it would slow the host
-    path it measures)."""
+    path it measures), and the program's own spans in its trace: while it
+    runs, a ``repro.telemetry.Tracer(sink="profiler")`` is the process's
+    tracer, so every program span is a host event of the trace
+    (``bench/spans.py``)."""
 
     active = True
 
     def __init__(self, log_dir: str):
         self.log_dir = log_dir
         self.summary = None
+        self.spans = None
         self.window_s = None
         self._ann = None
         self._t0 = None
+        self._prev_tracer = None
 
     def start(self):
         import jax
+        from repro.telemetry import Tracer, set_tracer
 
+        self._prev_tracer = set_tracer(Tracer(sink="profiler"))
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
@@ -131,6 +140,7 @@ class _Profiler:
 
     def stop(self):
         import jax
+        from repro.telemetry import set_tracer
 
         if self._ann is None:
             return
@@ -138,10 +148,12 @@ class _Profiler:
         self._ann.__exit__(None, None, None)
         self._ann = None
         jax.profiler.stop_trace()
+        set_tracer(self._prev_tracer)
 
     def reduce(self):
         ev = tracemod.load_events(tracemod.xplane_file(self.log_dir))
         self.summary = tracemod.reduce_events(ev)
+        self.spans = spansmod.reduce(ev)
         return self.summary
 
 
@@ -201,7 +213,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     _listen_compiles()
 
     dep = data.deployment(cfg)
-    pool = data.queries(dep, cfg["assumed"]["query_pool"], seed)
+    pool = data.queries(dep, cfg["assumed"]["query_pool"], seed,
+                        cfg.get("queries"))
     ctx = Context(config=cfg, params=params, dep=dep, pool=pool,
                   pool_f32=pool.astype("float32"), seed=seed, k=cfg["k"],
                   persistent=persistent_cache)
@@ -227,12 +240,15 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         if trace:
             prof.reduce()
     win["obs"]["trace"] = prof.summary
+    win["obs"]["spans"] = prof.spans
 
-    _, gt = reference.exact_knn(dep.data, pool, ctx.k)
+    metric = cfg["metric"]
+    _, gt = reference.exact_knn(dep.data, pool, ctx.k, metric=metric)
     checks, recall = check.compare(dep.data, pool, rows, ids, gt,
-                                   n_unanswered, cfg["checks"], ctx.k)
+                                   n_unanswered, cfg["checks"], ctx.k,
+                                   metric)
     if graphs is not None:
-        checks["nn_gap"] = {"value": check.nn_gap(dep.data, graphs),
+        checks["nn_gap"] = {"value": check.nn_gap(dep.data, graphs, metric),
                             "limit": cfg["checks"]["nn_gap_max"]}
     result = {
         "correct": check.passed(checks),

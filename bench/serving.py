@@ -133,7 +133,10 @@ class Serving:
             target, tdata = res.index, data
         else:
             raise ValueError(f"unknown topology {topo!r}")
-        scfg = ServingConfig(k=self.ctx.k, **self.params["server"])
+        # served under the configuration's metric unless the cell says
+        scfg = ServingConfig(**{"k": self.ctx.k,
+                                "metric": self.ctx.config["metric"],
+                                **self.params["server"]})
 
         async def go():
             self.srv = AnnServer(target, scfg, data=tdata)
@@ -165,7 +168,7 @@ class Serving:
         for s, ids in enumerate(topo.shard_ids):
             one = ShardTopology(
                 data=topo.data, shard_ids=[ids],
-                shard_graphs=[topo.shard_graphs[s]], metric=topo.metric,
+                shard_graphs=[topo.shard_graphs[s]], metric=scfg.metric,
                 centroids=topo.centroids[s:s + 1])
             for b in sizes:
                 search(one, np.resize(q, (b, q.shape[1])), scfg.k,
@@ -175,7 +178,7 @@ class Serving:
         cent = np.asarray(topo.centroids, np.float32)
         for n in range(1, scfg.max_batch + 1):
             _query_centroid_distances(np.resize(q, (n, q.shape[1])), cent,
-                                      topo.metric)
+                                      scfg.metric)
 
     def traced(self, prof, phase) -> int | None:
         """With the profiler on, run ``phase()`` (a further stretch of the

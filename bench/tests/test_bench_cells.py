@@ -6,16 +6,17 @@ import json
 import pytest
 
 from bench import common, run
-from bench.tests.tiny import CELLS, TINY
+from bench.tests.tiny import CELLS, IP_CELL, case
 
 SEED = 2**31 + 11  # past 32 signed bits, as the benchmark's seeds may be
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS + (IP_CELL,))
 def test_cell_prints_a_contract_line(cell, trace, interpret):
+    cell, overrides = case(cell)
     r = run.run_cell(cell, SEED, 1.0, bool(trace), require_tpu=False,
-                     persistent_cache=False, overrides=TINY)
+                     persistent_cache=False, overrides=overrides)
     json.loads(json.dumps(r))
     assert list(r)[:5] == ["correct", "attempted", "failed", "metrics",
                            "device"]

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from bench import run
-from bench.tests.tiny import TINY
+from bench.tests.tiny import IP_CELL, TINY, case
 
 SEED = 987654321
 
@@ -62,8 +62,9 @@ def _build_fault(kind, monkeypatch):
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_left_out",
                                    "answer_altered"])
 @pytest.mark.parametrize("cell", ["deep-f32.build", "bigann-u8.routed.closed",
-                                  "deep-f32.merged.closed"])
+                                  "deep-f32.merged.closed", IP_CELL])
 def test_fault_is_not_correct(cell, fault, monkeypatch):
+    cell, overrides = case(cell)
     if cell.endswith(".build"):
         _build_fault(fault, monkeypatch)
     else:
@@ -72,7 +73,7 @@ def test_fault_is_not_correct(cell, fault, monkeypatch):
         monkeypatch.setattr(server, "search",
                             _serving_fault(fault, server.search))
     r = run.run_cell(cell, SEED, 1.0, False, require_tpu=False,
-                     persistent_cache=False, overrides=TINY)
+                     persistent_cache=False, overrides=overrides)
     assert r["correct"] is False, r["checks"]
 
 

@@ -114,3 +114,38 @@ def test_untraced_result_keeps_its_keys(cell, interpret):
                                  "memory_peak_bytes"]
     e2e = {"deep-f32.build": "build_vec_per_s"}.get(cell, "serve_qps")
     assert set(r["metrics"]) == {e2e, "recall_at_10", "setup_s"}
+
+
+def test_profiler_installs_a_profiler_tracer_while_it_runs(tmp_path):
+    """A traced window puts the program's spans in the trace, and leaves
+    the process's tracer as it found it."""
+    from bench import run
+    from repro.telemetry import NULL_TRACER, current_tracer, use_tracer
+
+    prof = run._Profiler(str(tmp_path))
+    with use_tracer(None):
+        prof.start()
+        inside = current_tracer()
+        with inside.span("split.route"):
+            pass
+        prof.stop()
+        assert current_tracer() is NULL_TRACER
+    assert inside.enabled and inside._annotation is not None
+    prof.reduce()
+    assert prof.spans["split.route"][0] == 1
+    assert trace.WINDOW_SPAN not in prof.spans
+
+
+def test_untraced_run_installs_no_tracer(monkeypatch, interpret):
+    import repro.telemetry as telemetry
+    from bench import run
+    from bench.tests.tiny import TINY
+
+    def refuse(_tracer):
+        raise AssertionError("an untraced run installed a tracer")
+
+    monkeypatch.setattr(telemetry, "set_tracer", refuse)
+    r = run.run_cell("deep-f32.merged.closed", 5, 0.5, False,
+                     require_tpu=False, persistent_cache=False,
+                     overrides=TINY)
+    assert r["correct"] is True, r["checks"]

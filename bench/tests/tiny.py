@@ -17,3 +17,19 @@ TINY = {
 }
 CELLS = ("deep-f32.build", "bigann-u8.routed.closed",
          "deep-f32.merged.closed")
+
+# The tiny merged cell as an inner-product deployment with
+# out-of-distribution queries: the base, index and traffic of TINY, judged
+# and served under "ip", queries drawn off the base's clusters.
+OOD = {"source": "ood", "n_centres": 24, "shift": 0.5, "spread": 0.35,
+       "centre_seed": 1}
+IP_CELL = "deep-f32.merged.closed+ip"
+IP = {"config": dict(TINY["config"], metric="ip", queries=OOD,
+                     index=dict(TINY["config"]["index"], metric="ip")),
+      "params": TINY["params"]}
+
+
+def case(name: str) -> tuple[str, dict]:
+    """``(cell, overrides)`` of a test case: a cell at its TINY size, or
+    ``IP_CELL``, the merged cell under ``IP``."""
+    return ("deep-f32.merged.closed", IP) if name == IP_CELL else (name, TINY)

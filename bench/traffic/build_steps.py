@@ -70,7 +70,8 @@ class Driver:
         ids = np.concatenate([
             search(self.last.index, q[s:s + sp["chunk"]], self.ctx.k,
                    backend=sp["backend"], data=self.ctx.dep.data,
-                   width=sp["width"], n_entries=sp["n_entries"])[0]
+                   width=sp["width"], n_entries=sp["n_entries"],
+                   metric=self.ctx.config["metric"])[0]
             for s in range(0, len(q), sp["chunk"])
         ])
         return np.arange(len(q)), ids, 0
