@@ -76,6 +76,7 @@ class BuildResult:
     per_shard_s: list[float]
     n_distance_computations: int
     stats: dict
+    metric: str  # the IndexConfig.metric built with; serving defaults to it
     centroids: np.ndarray | None = None  # [n_shards, D] partition centroids
     shard_attempts: list[int] | None = None  # per-shard build attempts
     shard_errors: list[str] | None = None  # per-shard last retried error
@@ -121,18 +122,19 @@ class BuildResult:
             h.observe(s)
         return reg
 
-    def topology(self, data: np.ndarray, *, metric: str = "l2"):
+    def topology(self, data: np.ndarray, *, metric: str | None = None):
         """The search topology this build serves: merged systems expose the
         global graph, split-only systems the centroid-routed shard path
         (``repro.search.search(..., nprobe=...)`` prunes which shards each
-        query visits)."""
-        from repro.search import MergedTopology, ShardTopology
+        query visits).  ``metric`` defaults to the one built with."""
+        from repro.search import MergedTopology
 
         if self.index is not None:
-            return MergedTopology(data=data, index=self.index, metric=metric)
+            return MergedTopology(data=data, index=self.index,
+                                  metric=metric or self.metric)
         return self.shard_topology(data, metric=metric)
 
-    def shard_topology(self, data: np.ndarray, *, metric: str = "l2"):
+    def shard_topology(self, data: np.ndarray, *, metric: str | None = None):
         """The pre-merge routed serving view: the partition's (replicated)
         shards + centroids as a :class:`~repro.search.ShardTopology`.
 
@@ -146,7 +148,7 @@ class BuildResult:
             data=data,
             shard_ids=[s.ids for s in self.shards],
             shard_graphs=self.shard_graphs,
-            metric=metric,
+            metric=metric or self.metric,
             centroids=self.centroids,
         )
 
@@ -160,7 +162,7 @@ class BuildResult:
         width: int = 64,
         n_entries: int = 16,
         nprobe: int | None = None,
-        metric: str = "l2",
+        metric: str | None = None,
     ):
         """Serve queries on this build via :func:`repro.search.search` —
         the same call works for merged and split-only systems (``nprobe``
@@ -262,7 +264,14 @@ def build_scalegann(
     aborting the whole build; per-shard attempt counts / last retried
     errors land in ``BuildResult.shard_attempts`` / ``.shard_errors``, and
     a shard that exhausts its budget raises :class:`ShardBuildError`
-    carrying every failed shard's error."""
+    carrying every failed shard's error.
+
+    The whole build follows ``cfg.metric``: under ``"ip"`` the partition
+    assigns the MIPS-lifted vectors, the CAGRA shard graphs rank by
+    ``−x·y`` and the merge caps degree by it.  ``algo="vamana"`` is L2
+    only and refuses any other metric."""
+    if algo == "vamana":
+        vamana.require_l2(cfg)
     tr = current_tracer()
     t0 = time.perf_counter()
     with tr.span("build.partition", track="build", n=len(data)):
@@ -279,7 +288,7 @@ def build_scalegann(
     with tr.span("build.merge", track="build", n_shards=len(part.shards)):
         merged = merge_shard_indexes(
             part.shards, idxs, len(data), cfg.degree, data=data,
-            reference=reference,
+            reference=reference, metric=cfg.metric,
         )
     merge_s = time.perf_counter() - t0
     return BuildResult(
@@ -294,6 +303,7 @@ def build_scalegann(
         per_shard_s=per_shard_s,
         n_distance_computations=sum(i.n_distance_computations for i in idxs),
         stats=dict(part.stats),
+        metric=cfg.metric,
         centroids=part.centroids,
         shard_attempts=attempts,
         shard_errors=errors,
@@ -381,6 +391,7 @@ def build_split_only(
         per_shard_s=per_shard_s,
         n_distance_computations=sum(i.n_distance_computations for i in idxs),
         stats={"n": len(data), "replica_proportion": 0.0},
+        metric=cfg.metric,
         centroids=centroids,
         shard_attempts=attempts,
         shard_errors=errors,

@@ -3,6 +3,9 @@
 Like DiskANN, centroids are trained on a sample (``IndexConfig.kmeans_sample``)
 and the full dataset is then streamed block-by-block through the partitioner.
 The assignment hot loop is the pairwise-distance kernel (``kernels.ops``).
+It is L2 k-means; under an inner-product index the partitioner hands it the
+MIPS-lifted vectors (``core.partition.mips_lift``), in which L2 is the
+right distance.
 """
 
 from __future__ import annotations
@@ -56,9 +59,3 @@ def train_centroids(
     init = x[rng.choice(x.shape[0], size=k, replace=False)]
     centroids, _ = _lloyd(jnp.asarray(x), jnp.asarray(init), k, iters)
     return np.asarray(centroids)
-
-
-def kmeans_cost(data: np.ndarray, centroids: np.ndarray) -> float:
-    d = ops.pairwise_distance(jnp.asarray(data, jnp.float32),
-                              jnp.asarray(centroids, jnp.float32), "l2")
-    return float(jnp.mean(jnp.min(d, axis=1)))

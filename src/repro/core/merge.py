@@ -22,6 +22,7 @@ index, so it is written as a streaming pass over (graph, manifest) pairs:
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -30,9 +31,17 @@ from repro.core.partition import Shard
 from repro.telemetry import NULL_SPAN, current_tracer
 
 
-def entry_points(center: int, n_vectors: int, n: int = 16) -> np.ndarray:
-    """``center`` + ``n`` rows spread evenly over ``[0, n_vectors)``, as
-    sorted unique row ids.
+def entry_points(center: int, n_vectors: int, n: int = 16,
+                 cover: np.ndarray | None = None) -> np.ndarray:
+    """``center`` + ``n`` rows, as sorted unique row ids: the first
+    ``n // 2`` rows of ``cover`` at most, and the rest spread evenly over
+    ``[0, n_vectors)``.
+
+    ``cover`` holds one row of each graph component that ``center`` does
+    not belong to, largest component first (:attr:`GlobalIndex.component_roots`):
+    a beam never leaves the component it was seeded in, so a component
+    with no seed is never searched, and evenly spaced rows miss the
+    small ones.
 
     Always exactly ``min(n + 1, n_vectors)`` seeds: the center regularly
     collides with one of the ``linspace`` samples, and before the
@@ -41,9 +50,12 @@ def entry_points(center: int, n_vectors: int, n: int = 16) -> np.ndarray:
     and searches seeded one entry short.  A fixed count also keeps the seed
     array one shape for the jitted beams."""
     want = min(n + 1, n_vectors)
-    seeds = np.unique(np.concatenate(
-        [[center], np.linspace(0, n_vectors - 1, n, dtype=np.int64)]
-    ))
+    cover = (np.empty(0, np.int64) if cover is None
+             else np.asarray(cover, np.int64)[: n // 2])
+    seeds = np.unique(np.concatenate([
+        [center], cover,
+        np.linspace(0, n_vectors - 1, n - len(cover), dtype=np.int64),
+    ]))
     if len(seeds) < want:
         # top up with the smallest ids not already chosen — ids in
         # [0, want + len(seeds)) suffice by pigeonhole, so the scan
@@ -67,9 +79,48 @@ class GlobalIndex:
     def entry_points(self, n: int = 16) -> np.ndarray:
         """Medoid + a stratified sample — CAGRA-style multi-entry seeds (a
         merged kNN graph has only local edges; multiple entries restore
-        navigability; deterministic so serving replicas agree).  See
+        navigability; deterministic so serving replicas agree).  Where
+        the sample leaves a component of the graph without a seed, each
+        other component gets one (:attr:`component_roots`).  See
         :func:`entry_points`."""
-        return entry_points(self.medoid, self.n_vectors, n)
+        seeds = entry_points(self.medoid, self.n_vectors, n)
+        label, roots = self._components
+        if len(roots) == 0 or len(np.unique(label[seeds])) == len(roots) + 1:
+            return seeds
+        return entry_points(self.medoid, self.n_vectors, n, roots)
+
+    @property
+    def component_roots(self) -> np.ndarray:
+        """The smallest row id of every weakly connected component of the
+        graph but the medoid's, largest component first (ties by that id).
+
+        A merged graph of well-separated clusters falls apart into
+        components that no edge leads out of."""
+        return self._components[1]
+
+    @functools.cached_property
+    def _components(self) -> tuple[np.ndarray, np.ndarray]:
+        """Each row's component label, and :attr:`component_roots`.  Found
+        once, on first use; the graph is not changed after the merge."""
+        from scipy.sparse import csr_matrix
+        from scipy.sparse.csgraph import connected_components
+
+        n = self.n_vectors
+        if n == 0:
+            return np.empty(0, np.int64), np.empty(0, np.int64)
+        ok = self.graph >= 0
+        nbr = self.graph[ok]  # row-major: each row's edges in turn
+        indptr = np.concatenate([[0], np.cumsum(ok.sum(axis=1))])
+        adj = csr_matrix((np.ones(len(nbr), np.int8), nbr, indptr),
+                         shape=(n, n))
+        _, label = connected_components(adj, directed=True,
+                                        connection="weak")
+        size = np.bincount(label)
+        # np.unique's first index of each label is its smallest row id
+        lab, root = np.unique(label, return_index=True)
+        keep = lab != label[self.medoid]
+        lab, root = lab[keep], root[keep]
+        return label, root[np.lexsort((root, -size[lab]))]
 
     @property
     def degree(self) -> int:
@@ -139,19 +190,36 @@ def _edge_list(
 
 
 def _segment_distances(
-    data: np.ndarray, gids: np.ndarray, nbrs: np.ndarray,
+    data: np.ndarray, gids: np.ndarray, nbrs: np.ndarray, metric: str,
     block: int = 1 << 18,
 ) -> np.ndarray:
-    """Squared L2 between each edge's endpoints, blocked so the gather never
-    materializes more than ``2 · block · D`` f32 elements (``data`` may be a
-    memmap at the 10^5+ scale)."""
+    """The index metric's distance between each edge's endpoints: squared
+    L2, or ``−x_g·x_n`` under ``"ip"`` (what the shard kNN ranked the edges
+    by).  Blocked so the gather never materializes more than
+    ``2 · block · D`` f32 elements (``data`` may be a memmap at the 10^5+
+    scale)."""
+    if metric not in ("l2", "ip"):
+        raise ValueError(f"unknown metric {metric!r}")
     d = np.empty(len(gids), np.float32)
     for s in range(0, len(gids), block):
         sl = slice(s, s + block)
-        diff = (np.asarray(data[nbrs[sl]], np.float32)
-                - np.asarray(data[gids[sl]], np.float32))
-        d[sl] = np.einsum("ed,ed->e", diff, diff)
+        a = np.asarray(data[nbrs[sl]], np.float32)
+        b = np.asarray(data[gids[sl]], np.float32)
+        if metric == "ip":
+            d[sl] = -np.einsum("ed,ed->e", a, b)
+        else:
+            diff = a - b
+            d[sl] = np.einsum("ed,ed->e", diff, diff)
     return d
+
+
+def _order_key(d: np.ndarray) -> np.ndarray:
+    """int64 keys in ``[0, 2^32)`` that order as the float32 values ``d``,
+    negatives included: the bit pattern of a negative is flipped whole,
+    that of a non-negative gets its sign bit set.  ``−0`` is first made
+    ``+0``, so that equal values give equal keys."""
+    u = (d + np.float32(0.0)).view(np.uint32)
+    return np.where(u >> 31, ~u, u | np.uint32(1 << 31)).astype(np.int64)
 
 
 def _union_dedup_cap(
@@ -160,6 +228,7 @@ def _union_dedup_cap(
     n_total: int,
     degree: int,
     data: np.ndarray | None,
+    metric: str,
 ) -> np.ndarray:
     """Vectorized edge-union: one global ``(gid, neighbor)`` sort with
     segment-wise dedup and degree cap — replaces the per-gid python loop
@@ -167,9 +236,9 @@ def _union_dedup_cap(
 
     Per-gid semantics match the loop version: duplicate ``(gid, neighbor)``
     pairs collapse to their first appearance; the cap keeps the ``degree``
-    closest neighbors when ``data`` is given (ties broken by first-seen
-    order, the loop's stable ``argsort`` behavior) and the first-seen
-    ``degree`` otherwise.  The output is a pure function of the edge *set*,
+    closest neighbors by ``metric`` when ``data`` is given (ties broken by
+    first-seen order, the loop's stable ``argsort`` behavior) and the
+    first-seen ``degree`` otherwise.  The output is a pure function of the edge *set*,
     so the permutation-invariance contract (§V-C) is preserved — only the
     within-row order of an under-capacity ``data`` row differs from the
     loop (distance-sorted instead of first-seen; same id set).
@@ -195,11 +264,11 @@ def _union_dedup_cap(
     # cap: order each gid's unique neighbors by (distance, first-seen) or
     # (first-seen) alone, then keep ranks < degree.  A stable sort of the
     # first-seen order by an int64 (gid, distance) key sorts like
-    # lexsort((upos, d, ug)): non-negative f32 bit patterns order as values.
+    # lexsort((upos, d, ug)): _order_key orders signed f32 as values.
     if data is not None:
         with tr.span("merge.distances") if tr.enabled else NULL_SPAN:
-            d = _segment_distances(data, ug, un)
-            key = (ug << 32) | d.view(np.int32).astype(np.int64)
+            d = _segment_distances(data, ug, un, metric)
+            key = (ug << 32) | _order_key(d)
     else:
         key = ug
     with tr.span("merge.cap") if tr.enabled else NULL_SPAN:
@@ -221,6 +290,7 @@ def _union_dedup_cap_loop(
     n_total: int,
     degree: int,
     data: np.ndarray | None,
+    metric: str,
 ) -> np.ndarray:
     """Seed-loop reference for passes 2–3 (presized union buffers + one
     python iteration per global id) — kept for the merge parity tests and
@@ -260,7 +330,8 @@ def _union_dedup_cap_loop(
             if data is not None:
                 v = np.asarray(data[gid], np.float32)
                 cand = np.asarray(data[uniq], np.float32)
-                d = ((cand - v) ** 2).sum(axis=1)
+                d = (-(cand * v).sum(axis=1) if metric == "ip"
+                     else ((cand - v) ** 2).sum(axis=1))
                 uniq = uniq[np.argsort(d, kind="stable")[:degree]]
             else:
                 uniq = uniq[:degree]
@@ -277,12 +348,14 @@ def merge_shard_indexes(
     data: np.ndarray | None = None,
     centroid_of: np.ndarray | None = None,
     reference: bool = False,
+    metric: str = "l2",
 ) -> GlobalIndex:
     """Edge-union merge with degree cap.
 
     For each global vector, collect the union of its neighbor lists over all
     shards containing it.  Cap at ``degree``: if ``data`` is given, keep the
-    *closest* neighbors (distance-ordered, DiskANN behavior); otherwise keep
+    *closest* neighbors by the index's ``metric`` (squared L2, or the
+    largest inner products under ``"ip"``; DiskANN behavior); otherwise keep
     shard order (replicas append after originals).
 
     ``centroid_of`` ([N] shard id of the original assignment) is only used
@@ -296,7 +369,7 @@ def merge_shard_indexes(
     if len(shards) != len(indexes):
         raise ValueError("shards and indexes must align")
     union = _union_dedup_cap_loop if reference else _union_dedup_cap
-    graph = union(shards, indexes, n_total, degree, data)
+    graph = union(shards, indexes, n_total, degree, data, metric)
 
     medoid = 0
     if data is not None:

@@ -32,6 +32,13 @@ Both enforce identical invariants (tested):
   I2  a vector appears in ≤ ω clusters, no cluster twice;
   I3  every replica satisfies the ε/τ constraints at admission time;
   I4  no cluster exceeds its capacity.
+
+Every distance above is L2.  Under ``IndexConfig.metric == "ip"`` the
+partitioner works on the MIPS lift of the data (:func:`mips_lift`), in
+which a query's inner-product neighbours are its L2 neighbours, so k-means,
+ε·d, τ·r, the radii and θ keep their meaning unchanged.  Under an enabled
+tracer the stages are the spans ``partition.kmeans`` and
+``partition.assign`` (every block).
 """
 
 from __future__ import annotations
@@ -45,6 +52,7 @@ import numpy as np
 from repro.configs.base import IndexConfig
 from repro.core import kmeans as _kmeans
 from repro.kernels import ops
+from repro.telemetry import NULL_SPAN, current_tracer
 
 THETA_MIN, THETA_MAX = 0.02, 0.90
 
@@ -105,6 +113,22 @@ def cluster_capacity(cfg: IndexConfig, n_total: int) -> int:
     would further upper-bound this — see core.scheduler.shard_task_bytes)."""
     per = cfg.capacity_slack * cfg.omega * n_total / cfg.n_clusters
     return int(np.ceil(per))
+
+
+def mips_lift(x: np.ndarray) -> np.ndarray:
+    """The standard MIPS-to-L2 lift, ``[n, D]`` → ``[n, D + 1]`` float32:
+    ``x̃ = [x, sqrt(M² − ‖x‖²)]`` with ``M`` the largest norm in ``x``.
+
+    A query lifted as ``q̃ = [q, 0]`` has ``‖q̃ − x̃‖² = ‖q‖² + M² − 2·q·x``,
+    so its inner-product neighbours are the L2 neighbours of ``q̃`` among
+    the lifted rows, and an L2 ball in the lifted space is the right shard.
+    ``M² − ‖x‖²`` is clamped at 0 before the root: it cannot be negative
+    when ``M²`` is the largest of the same float32 norms, and the clamp
+    keeps a rounding elsewhere from ever making the root undefined."""
+    x = np.asarray(x, np.float32)
+    sq = np.einsum("nd,nd->n", x, x)
+    extra = np.sqrt(np.maximum(sq.max(initial=0.0) - sq, 0.0))
+    return np.concatenate([x, extra[:, None]], axis=1)
 
 
 def _distances_to_centroids(block: np.ndarray, centroids: np.ndarray) -> np.ndarray:
@@ -316,8 +340,9 @@ class Shard:
 @dataclasses.dataclass
 class PartitionResult:
     shards: list[Shard]
-    state: PartitionState
+    state: PartitionState  # in the space assigned in (lifted under "ip")
     stats: dict
+    dim: int  # D of the input vectors
 
     @property
     def replica_proportion(self) -> float:
@@ -325,10 +350,12 @@ class PartitionResult:
 
     @property
     def centroids(self) -> np.ndarray:
-        """[n_clusters, D] kmeans centroids the shards were assigned by.
-        Carried through the builder into serving so split-topology queries
-        can be routed to their nearest shards instead of broadcast."""
-        return self.state.centroids
+        """[n_clusters, D] kmeans centroids the shards were assigned by, in
+        the input space (under "ip" the first D coordinates of the lifted
+        centroids: the means of their members).  Carried through
+        ``build_scalegann`` into serving so split-topology queries can be
+        routed to their nearest shards instead of broadcast."""
+        return self.state.centroids[:, : self.dim]
 
 
 def iter_blocks(
@@ -354,13 +381,24 @@ def partition(
     ``selective=False`` reproduces DiskANN's uniform policy (every vector
     replicated to its next-nearest clusters up to ω, no ε/τ/θ pruning) — the
     'Original' column of paper Table IV.
+
+    Under ``cfg.metric == "ip"`` it assigns the lifted vectors
+    (:func:`mips_lift`, a float32 copy of ``N × (D + 1)``), and
+    ``centroids``, when given, are in that lifted space.
     """
     n = len(data)
+    dim = data.shape[1]
+    if cfg.metric == "ip":
+        data = mips_lift(data)
+    elif cfg.metric != "l2":
+        raise ValueError(f"unknown metric {cfg.metric!r}")
+    tr = current_tracer()
     if centroids is None:
-        centroids = _kmeans.train_centroids(
-            data, cfg.n_clusters, iters=cfg.kmeans_iters,
-            sample=cfg.kmeans_sample, seed=cfg.seed,
-        )
+        with tr.span("partition.kmeans") if tr.enabled else NULL_SPAN:
+            centroids = _kmeans.train_centroids(
+                data, cfg.n_clusters, iters=cfg.kmeans_iters,
+                sample=cfg.kmeans_sample, seed=cfg.seed,
+            )
     eff_cfg = cfg if selective else dataclasses.replace(
         cfg, epsilon=np.inf, tau0=np.inf, theta=1.0
     )
@@ -376,27 +414,28 @@ def partition(
     per_cluster_rep: list[list[np.ndarray]] = [[] for _ in range(cfg.n_clusters)]
     n_replicas = 0
     nearest_ok = 0
-    for b_idx, block in enumerate(iter_blocks(data, cfg.block_size)):
-        base = b_idx * cfg.block_size
-        tau = eff_cfg.tau(b_idx, n_blocks)
-        ba = assign_fn(np.asarray(block, np.float32), state, eff_cfg, tau)
-        gids = base + np.arange(len(block))
-        for c in np.unique(ba.original_cluster):
-            rows = gids[ba.original_cluster == c]
-            per_cluster[c].append(rows)
-            per_cluster_rep[c].append(np.zeros(len(rows), bool))
-        if len(ba.replicas):
-            for c in np.unique(ba.replicas[:, 1]):
-                rows = base + ba.replicas[ba.replicas[:, 1] == c, 0]
+    with tr.span("partition.assign") if tr.enabled else NULL_SPAN:
+        for b_idx, block in enumerate(iter_blocks(data, cfg.block_size)):
+            base = b_idx * cfg.block_size
+            tau = eff_cfg.tau(b_idx, n_blocks)
+            ba = assign_fn(np.asarray(block, np.float32), state, eff_cfg, tau)
+            gids = base + np.arange(len(block))
+            for c in np.unique(ba.original_cluster):
+                rows = gids[ba.original_cluster == c]
                 per_cluster[c].append(rows)
-                per_cluster_rep[c].append(np.ones(len(rows), bool))
-            n_replicas += len(ba.replicas)
-        # fairness stat: originals that got their true nearest cluster
-        true_nearest = np.argmin(
-            _distances_to_centroids(np.asarray(block, np.float32),
-                                    state.centroids), axis=1
-        )
-        nearest_ok += int((true_nearest == ba.original_cluster).sum())
+                per_cluster_rep[c].append(np.zeros(len(rows), bool))
+            if len(ba.replicas):
+                for c in np.unique(ba.replicas[:, 1]):
+                    rows = base + ba.replicas[ba.replicas[:, 1] == c, 0]
+                    per_cluster[c].append(rows)
+                    per_cluster_rep[c].append(np.ones(len(rows), bool))
+                n_replicas += len(ba.replicas)
+            # fairness stat: originals that got their true nearest cluster
+            true_nearest = np.argmin(
+                _distances_to_centroids(np.asarray(block, np.float32),
+                                        state.centroids), axis=1
+            )
+            nearest_ok += int((true_nearest == ba.original_cluster).sum())
 
     shards = [
         Shard(
@@ -414,4 +453,4 @@ def partition(
         "max_shard": max((len(s.ids) for s in shards), default=0),
         "capacity": state.capacity,
     }
-    return PartitionResult(shards=shards, state=state, stats=stats)
+    return PartitionResult(shards=shards, state=state, stats=stats, dim=dim)

@@ -72,6 +72,17 @@ class VamanaRoundState:
     R: int = 0
 
 
+def require_l2(cfg: IndexConfig) -> None:
+    """Refuse a metric the Vamana build does not compute: its searches,
+    RobustPrune and reverse edges are squared L2 (ROADMAP B2 is open for
+    Vamana), so any other ``cfg.metric`` would build an L2 graph without
+    saying so."""
+    if cfg.metric != "l2":
+        raise ValueError(
+            f"the Vamana build is L2 only (ROADMAP B2); got metric="
+            f"{cfg.metric!r}: build inner-product indexes with algo='cagra'")
+
+
 def _dists(data: np.ndarray, ids: np.ndarray, p: np.ndarray) -> np.ndarray:
     rows = data[ids].astype(np.float32)
     d = rows - p[None, :]
@@ -346,6 +357,7 @@ def build_shard_index_vamana(
     ``batch_size`` / ``alpha`` as the original build (checked where the
     checkpoint records them).
     """
+    require_l2(cfg)
     data = np.asarray(vectors, np.float32)
     n = len(data)
     R = min(cfg.degree, max(1, n - 1))
@@ -460,6 +472,7 @@ def build_shard_index_vamana_sequential(
     """Sequential (paper-faithful) Vamana build of one shard — the
     one-point-at-a-time CPU algorithm, kept as the seed-loop baseline the
     batched build is benched and parity-tested against."""
+    require_l2(cfg)
     data = np.asarray(vectors, np.float32)
     n = len(data)
     R = min(cfg.degree, max(1, n - 1))

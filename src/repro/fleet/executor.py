@@ -47,7 +47,8 @@ from repro.core.partition import partition
 from repro.core.scheduler import (CPU_MACHINE, V100_SPOT, CostGreedyPolicy,
                                   InstanceType, RuntimeModel, Task,
                                   calibrate_runtime)
-from repro.core.vamana import DEFAULT_BUILD_BATCH, build_shard_index_vamana
+from repro.core.vamana import (DEFAULT_BUILD_BATCH, build_shard_index_vamana,
+                               require_l2)
 from repro.fleet.checkpoint import CheckpointStore, ShardCheckpoint
 from repro.fleet.injector import Preempted, PreemptionInjector
 from repro.telemetry import MetricsRegistry, current_tracer
@@ -172,6 +173,7 @@ def build_scalegann_fleet(
             "fleet builds checkpoint at Vamana round grain; "
             f"algo={algo!r} is not supported (use build_scalegann)"
         )
+    require_l2(cfg)
     policy = policy or CostGreedyPolicy()
     store = checkpoint_store or CheckpointStore()
     nb = batch_size or DEFAULT_BUILD_BATCH
@@ -496,7 +498,8 @@ def build_scalegann_fleet(
     t0 = time.perf_counter()
     with tr.span("fleet.merge", track="fleet"):
         merged = merge_shard_indexes(
-            shards, results, len(data), cfg.degree, data=data
+            shards, results, len(data), cfg.degree, data=data,
+            metric=cfg.metric,
         )
     merge_s = time.perf_counter() - t0
     makespan_s = time.perf_counter() - t_all
@@ -515,6 +518,7 @@ def build_scalegann_fleet(
             i.n_distance_computations for i in results
         ),
         stats=dict(part.stats),
+        metric=cfg.metric,
         centroids=part.centroids,
         shard_attempts=attempts,
         shard_errors=errors,

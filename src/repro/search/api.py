@@ -176,9 +176,10 @@ def search(
 
     ``n_entries`` — seeds per beam besides the center entry: the merged
     graph's medoid, or each probed shard's centroid-nearest row, plus
-    ``n_entries`` rows spread evenly over the index or shard
-    (:func:`repro.core.merge.entry_points`).  ``1`` seeds from the center
-    entry alone.
+    ``n_entries`` rows spread evenly over the index or shard; on a merged
+    graph that falls apart, up to half of them are one row of each other
+    component instead (:func:`repro.core.merge.entry_points`).  ``1``
+    seeds from the center entry alone.
 
     ``dtype`` — the staged-precision distance path (PilotANN-style: cheap
     traversal, exact finish).  ``"f32"`` (default) is bit-identical to the
