@@ -24,6 +24,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cagra import ShardIndex
@@ -195,9 +197,10 @@ def _segment_distances(
 ) -> np.ndarray:
     """The index metric's distance between each edge's endpoints: squared
     L2, or ``−x_g·x_n`` under ``"ip"`` (what the shard kNN ranked the edges
-    by).  Blocked so the gather never materializes more than
-    ``2 · block · D`` f32 elements (``data`` may be a memmap at the 10^5+
-    scale)."""
+    by), gathered and summed in numpy.  The host path of
+    :func:`_edge_distances`, for data too large to hold on the device.
+    Blocked so the gather never materializes more than ``2 · block · D``
+    f32 elements (``data`` may be a memmap at the 10^5+ scale)."""
     if metric not in ("l2", "ip"):
         raise ValueError(f"unknown metric {metric!r}")
     d = np.empty(len(gids), np.float32)
@@ -211,6 +214,67 @@ def _segment_distances(
             diff = a - b
             d[sl] = np.einsum("ed,ed->e", diff, diff)
     return d
+
+
+_EDGE_BLOCK = 1 << 16  # edges per device block: one compiled shape
+
+
+def _device_bytes_limit() -> int | None:
+    """The default device's memory in bytes; ``None`` where the backend
+    reports none (the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def _fits_on_device(data: np.ndarray) -> bool:
+    """Whether the float32 copy of ``data`` fits in half of the device's
+    memory, with row ids that int32 holds."""
+    n, dim = data.shape
+    limit = _device_bytes_limit()
+    return n < 2**31 and (limit is None or 4 * n * dim <= limit // 2)
+
+
+@functools.partial(jax.jit, static_argnames=("metric",))
+def _block_distances(x: jax.Array, gids: jax.Array, nbrs: jax.Array,
+                     metric: str) -> jax.Array:
+    """:func:`_segment_distances` of one block of edges, on the device:
+    float32 elementwise products summed over D (no dot, which a TPU runs
+    as one bfloat16 pass at default precision)."""
+    a = x[nbrs].astype(jnp.float32)
+    b = x[gids].astype(jnp.float32)
+    if metric == "ip":
+        return -jnp.sum(a * b, axis=-1)
+    diff = a - b
+    return jnp.sum(diff * diff, axis=-1)
+
+
+def _edge_distances(
+    data: np.ndarray, gids: np.ndarray, nbrs: np.ndarray, metric: str,
+) -> tuple[np.ndarray, bool, int]:
+    """:func:`_segment_distances` of every edge, and the path that ran:
+    ``(d, resident, h2d_bytes)``.
+
+    Where :func:`_fits_on_device`, ``data`` is uploaded once in its own
+    dtype (f64 as f32) and each block of :data:`_EDGE_BLOCK` edges uploads
+    its int32 endpoint ids; the last block is padded with row 0 and its
+    padding dropped.  Every block is dispatched before ``d`` is read back.
+    Nothing stays on the device after the call.  Otherwise the numpy path
+    runs and uploads nothing.  The two paths sum in another order, so a
+    distance may differ in its last bits."""
+    if metric not in ("l2", "ip"):
+        raise ValueError(f"unknown metric {metric!r}")
+    if not _fits_on_device(data):
+        return _segment_distances(data, gids, nbrs, metric), False, 0
+    host = np.asarray(data)
+    if host.dtype.itemsize > 4:
+        host = host.astype(np.float32)
+    x = jnp.asarray(host)
+    n, blk = len(gids), _EDGE_BLOCK
+    pad = -n % blk
+    g = np.pad(gids.astype(np.int32), (0, pad)).reshape(-1, blk)
+    e = np.pad(nbrs.astype(np.int32), (0, pad)).reshape(-1, blk)
+    out = [_block_distances(x, gb, eb, metric) for gb, eb in zip(g, e)]
+    d = np.concatenate(jax.device_get(out))[:n]
+    return d, True, host.nbytes + g.nbytes + e.nbytes
 
 
 def _order_key(d: np.ndarray) -> np.ndarray:
@@ -243,8 +307,15 @@ def _union_dedup_cap(
     within-row order of an under-capacity ``data`` row differs from the
     loop (distance-sorted instead of first-seen; same id set).
 
+    The distances are computed on the device where the float32 copy of
+    ``data`` fits in half of its memory, else in numpy
+    (:func:`_edge_distances`); a pair of candidates within a few float32
+    ulps of each other at the cap may swap between the two paths.
+
     Under an enabled tracer its stages are the spans ``merge.edges``,
-    ``merge.sort``, ``merge.distances`` and ``merge.cap``.
+    ``merge.sort``, ``merge.distances`` and ``merge.cap``;
+    ``merge.distances`` carries ``resident`` (the device path ran),
+    ``n_edges`` (deduplicated edges) and ``h2d_bytes`` (uploaded).
     """
     tr = current_tracer()
     graph = np.full((n_total, degree), -1, np.int32)
@@ -266,8 +337,9 @@ def _union_dedup_cap(
     # first-seen order by an int64 (gid, distance) key sorts like
     # lexsort((upos, d, ug)): _order_key orders signed f32 as values.
     if data is not None:
-        with tr.span("merge.distances") if tr.enabled else NULL_SPAN:
-            d = _segment_distances(data, ug, un, metric)
+        with tr.span("merge.distances") if tr.enabled else NULL_SPAN as sp:
+            d, resident, h2d = _edge_distances(data, ug, un, metric)
+            sp.set(resident=resident, n_edges=len(ug), h2d_bytes=h2d)
             key = (ug << 32) | _order_key(d)
     else:
         key = ug
@@ -294,7 +366,8 @@ def _union_dedup_cap_loop(
 ) -> np.ndarray:
     """Seed-loop reference for passes 2–3 (presized union buffers + one
     python iteration per global id) — kept for the merge parity tests and
-    the ``bench_build.py`` seed-loop baseline."""
+    the ``bench_build.py`` seed-loop baseline.  Its distances stay numpy
+    on either platform, so it is independent of the device path."""
     # Pass 1: count edges per global id to presize the union buffers.
     counts = np.zeros(n_total, np.int64)
     for shard, idx in zip(shards, indexes):

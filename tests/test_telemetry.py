@@ -18,7 +18,10 @@ import numpy as np
 import pytest
 
 from repro.configs.base import IndexConfig
+from repro.core import merge
 from repro.core.builder import build_scalegann
+from repro.core.cagra import ShardIndex
+from repro.core.merge import merge_shard_indexes
 from repro.core.scheduler import RuntimeModel
 from repro.data.synthetic import make_clustered
 from repro.fleet import PreemptionInjector, build_scalegann_fleet
@@ -352,6 +355,29 @@ def test_merged_h2d_bytes_are_the_uploaded_shapes(tiny_build):
     assert stats.n_beam_calls == 1
     assert stats.h2d_bytes == (n * d * 4 + res.index.graph.size * 4
                                + entries * 4 + len(ds.queries) * d * 4)
+
+
+def test_merge_distances_span_records_its_path(tiny_build):
+    ds, cfg, res = tiny_build
+    n, d = ds.data.shape
+    edges = set()
+    for s, g in zip(res.shards, res.shard_graphs):
+        for row, gid in enumerate(s.ids):
+            edges |= {(int(gid), int(s.ids[j])) for j in g[row]
+                      if j >= 0 and s.ids[j] != gid}
+    idxs = [ShardIndex(graph=g, n_distance_computations=0)
+            for g in res.shard_graphs]
+    tracer = Tracer(ManualClock())
+    with use_tracer(tracer):
+        merge_shard_indexes(res.shards, idxs, n, cfg.degree, data=ds.data)
+    (span,) = [e for e in tracer.to_chrome()["traceEvents"]
+               if e["name"] == "merge.distances"]
+    blocks = -(-len(edges) // merge._EDGE_BLOCK)
+    # the CPU backend reports no memory limit: the device path runs
+    args = {k: span["args"][k] for k in ("resident", "n_edges", "h2d_bytes")}
+    assert args == {
+        "resident": True, "n_edges": len(edges),
+        "h2d_bytes": n * d * 4 + 2 * blocks * merge._EDGE_BLOCK * 4}
 
 
 # ---------------------------------------------------------------------------
