@@ -1,4 +1,4 @@
-"""Benchmark aggregator: one harness per paper table/figure + roofline.
+"""Benchmark aggregator: one harness per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--only NAME] [--fast]
 
@@ -25,7 +25,7 @@ def main() -> int:
     if args.fast:
         os.environ["REPRO_BENCH_FAST"] = "1"
 
-    from benchmarks import (cost_analysis, roofline, table1_breakdown,
+    from benchmarks import (cost_analysis, table1_breakdown,
                             table2_gpu_vs_cpu, table4_selectivity,
                             table5_systems, table6_degree, table7_multigpu)
 
@@ -37,7 +37,6 @@ def main() -> int:
         "table6_degree": table6_degree.main,
         "table7_multigpu": table7_multigpu.main,
         "cost_analysis": cost_analysis.main,
-        "roofline": roofline.main,
     }
     failures = []
     claims_true = claims_total = 0

@@ -1,9 +1,7 @@
 """ScaleGANN core — the paper's contribution (partition / build / merge /
-search / spot scheduling / cost), in JAX + numpy orchestration.
+spot scheduling / cost), in JAX + numpy orchestration.
 
-Query serving lives in :mod:`repro.search` (backend-pluggable engine); the
-``search_index`` / ``split_search`` names re-exported here are deprecation
-shims kept for one release.
+Query serving lives in :mod:`repro.search` (backend-pluggable engine).
 """
 
 from repro.core.builder import (  # noqa: F401
@@ -13,7 +11,6 @@ from repro.core.builder import (  # noqa: F401
     build_scalegann,
 )
 from repro.core.merge import GlobalIndex, merge_shard_indexes  # noqa: F401
-from repro.core.search import search_index, split_search  # noqa: F401
 
 # NOTE: `repro.core.partition` (module) intentionally not re-exported as a
 # function here — it would shadow the submodule name.  Use

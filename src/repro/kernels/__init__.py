@@ -13,17 +13,12 @@ import this package, so jax import cost stays off their paths.
 """
 
 from repro.kernels.beam import fused_beam
-from repro.kernels.ops import (flash_attention, flash_attention_jnp,
-                               flash_decode, knn, pairwise_distance,
-                               pairwise_distance_u8, pallas_mode,
-                               rerank_exact, set_pallas_mode)
+from repro.kernels.ops import (knn, pairwise_distance, pairwise_distance_u8,
+                               pallas_mode, rerank_exact, set_pallas_mode)
 from repro.kernels.topk import bitonic_sort_lex, merge_topk
 
 __all__ = [
     "bitonic_sort_lex",
-    "flash_attention",
-    "flash_attention_jnp",
-    "flash_decode",
     "fused_beam",
     "knn",
     "merge_topk",

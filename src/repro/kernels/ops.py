@@ -7,15 +7,10 @@ Dispatch policy (``set_pallas_mode`` / ``REPRO_PALLAS`` env var):
   * ``force_interpret`` — run the Pallas kernels in interpret mode (tests use
                            this to validate kernel semantics on CPU);
   * ``off``             — always the jnp reference.
-
-Also hosts ``flash_attention_jnp`` — the *differentiable* chunked-attention
-used by train_step and by the dry-run lowering (memory-safe at 32k+ context,
-online softmax over KV chunks, scan over Q chunks).
 """
 
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
@@ -23,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import distance as _distance
-from repro.kernels import flash_attention as _flash
 from repro.kernels import ref
 from repro.kernels import topk as _topk
 
@@ -176,96 +170,3 @@ def knn(q, x, k: int, metric: str = "l2", *, block: int = 128):
         interpret=interp,
     )
     return d[:m], i[:m]
-
-
-# ---------------------------------------------------------------------------
-# Attention
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "q_chunk", "kv_chunk")
-)
-def flash_attention_jnp(
-    q, k, v, *, causal: bool = True, scale: float | None = None,
-    q_chunk: int = 512, kv_chunk: int = 1024,
-):
-    """Differentiable chunked flash attention (pure jnp, scan×scan).
-
-    q: [B,H,S,Dh], k/v: [B,Hkv,T,Dh].  Memory: O(bq·bkv) logits per step.
-    """
-    b, h, s, dh = q.shape
-    hkv, t = k.shape[1], k.shape[2]
-    group = h // hkv
-    scale = scale if scale is not None else dh**-0.5
-    q_chunk = min(q_chunk, s)
-    kv_chunk = min(kv_chunk, t)
-    if s % q_chunk or t % kv_chunk:
-        raise ValueError("sequence lengths must divide the chunk sizes")
-    nq, nkv = s // q_chunk, t // kv_chunk
-    offset = t - s
-
-    qs = q.reshape(b, hkv, group, nq, q_chunk, dh).transpose(3, 0, 1, 2, 4, 5)
-    ks = k.reshape(b, hkv, nkv, kv_chunk, dh).transpose(2, 0, 1, 3, 4)
-    vs = v.reshape(b, hkv, nkv, kv_chunk, dh).transpose(2, 0, 1, 3, 4)
-
-    def q_step(_, iq_qc):
-        iq, qc = iq_qc  # qc: [b, hkv, group, q_chunk, dh]
-        qc = qc.astype(jnp.float32) * scale
-
-        def kv_step(carry, jk_kv):
-            m_prev, l_prev, acc = carry
-            jk, kc, vc = jk_kv
-            sij = jnp.einsum(
-                "bhgqd,bhkd->bhgqk", qc, kc.astype(jnp.float32)
-            )
-            if causal:
-                q_pos = iq * q_chunk + jnp.arange(q_chunk)[:, None] + offset
-                k_pos = jk * kv_chunk + jnp.arange(kv_chunk)[None, :]
-                sij = jnp.where(q_pos >= k_pos, sij, -1e30)
-            m_new = jnp.maximum(m_prev, sij.max(axis=-1, keepdims=True))
-            p = jnp.exp(sij - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-            acc = acc * alpha + jnp.einsum(
-                "bhgqk,bhkd->bhgqd", p, vc.astype(jnp.float32)
-            )
-            return (m_new, l_new, acc), None
-
-        init = (
-            jnp.full((b, hkv, group, q_chunk, 1), -1e30, jnp.float32),
-            jnp.zeros((b, hkv, group, q_chunk, 1), jnp.float32),
-            jnp.zeros((b, hkv, group, q_chunk, dh), jnp.float32),
-        )
-        (m_f, l_f, acc), _ = jax.lax.scan(
-            kv_step, init, (jnp.arange(nkv), ks, vs)
-        )
-        out = acc / jnp.maximum(l_f, 1e-30)
-        return None, out.astype(q.dtype)
-
-    _, outs = jax.lax.scan(q_step, None, (jnp.arange(nq), qs))
-    # outs: [nq, b, hkv, group, q_chunk, dh]
-    out = outs.transpose(1, 2, 3, 0, 4, 5).reshape(b, h, s, dh)
-    return out
-
-
-def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None):
-    """Serving-path attention: Pallas kernel on TPU/interpret, chunked jnp
-    otherwise.  For the training path call ``flash_attention_jnp`` directly
-    (differentiable)."""
-    use, interp = _use_pallas()
-    if use:
-        return _flash.flash_attention_pallas(
-            q, k, v, causal=causal, scale=scale, interpret=interp
-        )
-    return flash_attention_jnp(q, k, v, causal=causal, scale=scale)
-
-
-def flash_decode(q, k_cache, v_cache, cache_len, *, scale: float | None = None):
-    """One-token decode attention. q: [B,H,Dh], cache: [B,Hkv,T,Dh]."""
-    use, interp = _use_pallas()
-    if use:
-        return _flash.flash_decode_pallas(
-            q, k_cache, v_cache, cache_len, scale=scale, interpret=interp
-        )
-    return ref.decode_attention(q, k_cache, v_cache, cache_len, scale)

@@ -119,56 +119,6 @@ def knn(q: jax.Array, x: jax.Array, k: int, metric: str = "l2"):
 
 
 # ---------------------------------------------------------------------------
-# Attention
-# ---------------------------------------------------------------------------
-
-
-def mha_attention(q, k, v, *, causal: bool = True, scale: float | None = None):
-    """Reference multi-head attention.
-
-    q: [B, H, S, Dh], k/v: [B, Hkv, T, Dh] with H % Hkv == 0 (GQA).
-    Returns [B, H, S, Dh] in q.dtype; softmax in float32.
-    """
-    b, h, s, dh = q.shape
-    hkv = k.shape[1]
-    group = h // hkv
-    scale = scale if scale is not None else dh**-0.5
-    qf = q.astype(jnp.float32) * scale
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    qf = qf.reshape(b, hkv, group, s, dh)
-    logits = jnp.einsum("bhgsd,bhtd->bhgst", qf, kf)
-    if causal:
-        t = k.shape[2]
-        # query position i attends to key positions <= i + (t - s)
-        mask = (jnp.arange(s)[:, None] + (t - s)) >= jnp.arange(t)[None, :]
-        logits = jnp.where(mask[None, None, None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgst,bhtd->bhgsd", probs, vf)
-    return out.reshape(b, h, s, dh).astype(q.dtype)
-
-
-def decode_attention(q, k_cache, v_cache, cache_len=None, scale=None):
-    """One-token attention against a KV cache.
-
-    q: [B, H, Dh]; k_cache/v_cache: [B, Hkv, T, Dh]; cache_len: [B] valid
-    lengths (None -> all T valid). Returns [B, H, Dh].
-    """
-    b, h, dh = q.shape
-    hkv, t = k_cache.shape[1], k_cache.shape[2]
-    group = h // hkv
-    scale = scale if scale is not None else dh**-0.5
-    qf = (q.astype(jnp.float32) * scale).reshape(b, hkv, group, dh)
-    logits = jnp.einsum("bhgd,bhtd->bhgt", qf, k_cache.astype(jnp.float32))
-    if cache_len is not None:
-        mask = jnp.arange(t)[None, :] < cache_len[:, None]  # [B, T]
-        logits = jnp.where(mask[:, None, None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgt,bhtd->bhgd", probs, v_cache.astype(jnp.float32))
-    return out.reshape(b, h, dh).astype(q.dtype)
-
-
-# ---------------------------------------------------------------------------
 # K-means assignment (distance + argmin) — partitioning hot loop
 # ---------------------------------------------------------------------------
 

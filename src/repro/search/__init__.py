@@ -1,19 +1,14 @@
 """``repro.search`` — the unified, backend-pluggable query engine.
 
 One public API, :func:`search`, serves every query topology in the repo
-(merged ScaleGANN/DiskANN index, split-only shards — centroid-routed via
-``nprobe`` or full scatter — and the retrieval-attention inner-product
-path) on any registered backend:
+(merged ScaleGANN/DiskANN index, and split-only shards — centroid-routed
+via ``nprobe`` or full scatter) on any registered backend:
 
   * ``numpy``  — reference; exact DiskANN GreedySearch semantics + stats;
   * ``jax``    — vmapped batched beam search, multi-entry seeding,
                  sorted-merge dedup, convergence early-exit;
   * ``pallas`` — traversal in JAX, distance tiles + running top-k staged
                  through ``repro.kernels`` (interpret mode off-TPU).
-
-Replaces the four divergent implementations that used to live in
-``repro.core.search`` (now a deprecation shim) and
-``repro.serve.retrieval_attention._ip_search``.
 """
 
 from repro.search.api import (SearchBackend, available_backends,  # noqa: F401

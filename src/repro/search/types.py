@@ -14,8 +14,8 @@ systems answer queries with the same beam search):
 
 Both carry their vectors and metric so a backend gets everything it needs
 from a single object, and ``as_topology`` adapts the loose
-``(data, index)`` / ``(data, shard_ids, shard_graphs)`` calling conventions
-of the old ``core.search`` module.
+``(data, index)`` / ``(data, shard_ids, shard_graphs)`` calling
+conventions.
 """
 
 from __future__ import annotations
@@ -353,8 +353,8 @@ def as_topology(index_or_shards, data=None, *, metric: str = "l2") -> Topology:
     (requires ``data``), or a ``(shard_ids, shard_graphs)`` pair (requires
     ``data``).
     """
-    from repro.core.merge import GlobalIndex  # deferred: avoids an import
-    # cycle (repro.core.search re-exports from repro.search)
+    from repro.core.merge import GlobalIndex  # deferred: importing
+    # repro.core loads the builders, and with them jax
 
     if isinstance(index_or_shards, (MergedTopology, ShardTopology)):
         return index_or_shards

@@ -1,14 +1,8 @@
 """``repro.serving`` — the asyncio micro-batching **ANN query** server.
 
-Naming, because the repo has two serving layers:
-
-  * ``repro.serving`` (this package) — ANN *query* serving: accumulates
-    single-query ``submit()`` calls into engine-sized batches and drains
-    them through :func:`repro.search.search` (any topology, any backend,
-    routed ``nprobe`` included).
-  * ``repro.serve`` — the **LM decode** serving engine (prefill + decode
-    slot batching for the language-model substrate).  Nothing ANN-related
-    is exported from there.
+It accumulates single-query ``submit()`` calls into engine-sized batches
+and drains them through :func:`repro.search.search` (any topology, any
+backend, routed ``nprobe`` included).
 
 Public surface::
 

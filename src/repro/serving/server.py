@@ -19,9 +19,6 @@ request's future, and feeds :class:`~repro.serving.stats.ServerStats`.
 Batch shapes are padded to powers of two (:func:`bucket_batch_size`) so
 the jitted backends retrace O(log max_batch) times, not once per
 occupancy — and those shapes are pre-traced at startup.
-
-Not to be confused with ``repro.serve`` — the *LM decode* serving engine;
-see that module's docstring for the naming split.
 """
 
 from __future__ import annotations

@@ -30,17 +30,3 @@ def test_serve_ann():
     assert p.returncode == 0, p.stderr[-2000:]
     assert "achieved QPS" in p.stdout
     assert "recall@10" in p.stdout
-
-
-def test_serve_lm():
-    p = _run("serve_lm.py", "--requests", "2", "--max-new", "4")
-    assert p.returncode == 0, p.stderr[-2000:]
-    assert "served 2 requests" in p.stdout
-
-
-def test_train_lm_short(tmp_path):
-    p = _run("train_lm.py", "--steps", "6", "--batch", "2", "--seq", "64",
-             "--ckpt-dir", str(tmp_path), "--ckpt-every", "3")
-    assert p.returncode == 0, p.stderr[-2000:]
-    assert "checkpoint →" in p.stdout
-    assert "done: final loss" in p.stdout

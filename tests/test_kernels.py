@@ -8,8 +8,6 @@ from numpy.testing import assert_allclose
 
 from repro.kernels import ops, ref
 from repro.kernels.distance import pairwise_distance_pallas
-from repro.kernels.flash_attention import (flash_attention_pallas,
-                                           flash_decode_pallas)
 from repro.kernels.topk import bitonic_sort_pairs, knn_pallas
 
 
@@ -65,17 +63,49 @@ def test_bitonic_sort_matches_numpy(rng):
                                                        axis=1), rtol=1e-6)
 
 
-@pytest.mark.parametrize("m,n,d,k", [(128, 256, 128, 8), (128, 128, 256, 32)])
-def test_knn_kernel(rng, m, n, d, k):
-    q = _rand(rng, (m, d), jnp.float32)
-    x = _rand(rng, (n, d), jnp.float32)
-    dist, idx = knn_pallas(q, x, k, interpret=True)
-    want_d, want_i = ref.knn(q, x, k)
+# (m, n, n_real, D, k, data): the two generic shapes, then the benchmark
+# configurations' widths — deep-f32 (D = 96, f32), bigann-u8 (D = 128,
+# uint8 codes) and text2image-ip (D = 200, f32) — at the build's k = L + 1
+# = 129 and around it, over a ragged n_real the padding rows must not reach
+_KNN_CASES = [
+    pytest.param(128, 256, 256, 128, 8, "f32", id="m128-n256-d128-k8"),
+    pytest.param(128, 128, 128, 256, 32, "f32", id="m128-n128-d256-k32"),
+] + [
+    pytest.param(128, 384, 300, d, k, data, id=f"{shape}-D{d}-k{k}")
+    for shape, d, data in (("deep-f32", 96, "f32"),
+                           ("bigann-u8", 128, "u8"),
+                           ("text2image-ip", 200, "f32"))
+    for k in (10, 64, 128, 129)
+]
+
+
+@pytest.mark.parametrize("m,n,n_real,d,k,data", _KNN_CASES)
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_knn_kernel(rng, m, n, n_real, d, k, data, metric):
+    def draw(rows):
+        if data == "u8":
+            return rng.integers(0, 256, (rows, d)).astype(np.float32)
+        return rng.normal(size=(rows, d)).astype(np.float32)
+
+    lanes = -(-d // 128) * 128  # zero lanes change neither metric
+    q = np.zeros((m, lanes), np.float32)
+    x = np.zeros((n, lanes), np.float32)
+    q[:, :d] = draw(m)
+    x[:n_real, :d] = draw(n_real)
+    # padding rows that would win under either metric if not masked
+    decoys = 4.0 * np.resize(q[:, :d], (n - n_real, d))
+    x[n_real:, :d] = decoys if metric == "ip" else q[: n - n_real, :d]
+    q, x = jnp.asarray(q), jnp.asarray(x)
+    dist, idx = knn_pallas(q, x, k, metric=metric, n_real=n_real,
+                           interpret=True)
+    want_d, _ = ref.knn(q, x[:n_real], k, metric)
     assert_allclose(np.asarray(dist), np.asarray(want_d), rtol=1e-3,
                     atol=1e-3)
+    idx = np.asarray(idx)
+    assert idx.min() >= 0 and idx.max() < n_real
     # indices may differ on ties; check distance agreement instead
-    got_rows = np.asarray(ref.pairwise_l2(q, x))[
-        np.arange(m)[:, None], np.asarray(idx)
+    got_rows = np.asarray(ref.pairwise_distance(q, x[:n_real], metric))[
+        np.arange(m)[:, None], idx
     ]
     assert_allclose(got_rows, np.asarray(want_d), rtol=1e-3, atol=1e-3)
 
@@ -85,53 +115,3 @@ def test_knn_kernel_masks_padding(rng):
     x = _rand(rng, (256, 128), jnp.float32)
     d, i = knn_pallas(q, x, 4, n_real=100, interpret=True)
     assert int(np.asarray(i).max()) < 100
-
-
-# ---------------------------------------------------------------------------
-# flash attention / decode
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("h,hkv", [(8, 8), (8, 2)])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_kernel(rng, h, hkv, causal):
-    b, s, dh = 2, 512, 64
-    q = _rand(rng, (b, h, s, dh), jnp.float32)
-    k = _rand(rng, (b, hkv, s, dh), jnp.float32)
-    v = _rand(rng, (b, hkv, s, dh), jnp.float32)
-    got = flash_attention_pallas(q, k, v, causal=causal, interpret=True)
-    want = ref.mha_attention(q, k, v, causal=causal)
-    assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
-
-
-def test_flash_attention_jnp_matches_ref(rng):
-    b, h, hkv, s, dh = 2, 8, 4, 256, 32
-    q = _rand(rng, (b, h, s, dh), jnp.float32)
-    k = _rand(rng, (b, hkv, s, dh), jnp.float32)
-    v = _rand(rng, (b, hkv, s, dh), jnp.float32)
-    got = ops.flash_attention_jnp(q, k, v, q_chunk=64, kv_chunk=128)
-    want = ref.mha_attention(q, k, v)
-    assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
-
-
-def test_flash_attention_jnp_cross_lengths(rng):
-    """T > S (prefix cache): positions must offset correctly."""
-    b, h, s, t, dh = 1, 4, 128, 256, 32
-    q = _rand(rng, (b, h, s, dh), jnp.float32)
-    k = _rand(rng, (b, h, t, dh), jnp.float32)
-    v = _rand(rng, (b, h, t, dh), jnp.float32)
-    got = ops.flash_attention_jnp(q, k, v, q_chunk=64, kv_chunk=64)
-    want = ref.mha_attention(q, k, v)
-    assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
-
-
-@pytest.mark.parametrize("lens", [[512, 512], [100, 317]])
-def test_flash_decode_kernel(rng, lens):
-    b, h, hkv, t, dh = 2, 8, 4, 512, 64
-    q = _rand(rng, (b, h, dh), jnp.float32)
-    k = _rand(rng, (b, hkv, t, dh), jnp.float32)
-    v = _rand(rng, (b, hkv, t, dh), jnp.float32)
-    cl = jnp.asarray(lens, jnp.int32)
-    got = flash_decode_pallas(q, k, v, cl, interpret=True)
-    want = ref.decode_attention(q, k, v, cl)
-    assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)

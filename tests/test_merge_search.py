@@ -94,17 +94,6 @@ def test_batch_search_matches_serial(ds, built):
     assert agree > 0.7
 
 
-def test_deprecated_core_search_shim(ds, built):
-    """Old entry points still work (one release of back-compat)."""
-    from repro.core.search import search_index
-
-    with pytest.warns(DeprecationWarning):
-        ids, st = search_index(ds.data, built.index, ds.queries[:4], 10,
-                               width=64)
-    ids_n, _ = search(built.index, ds.queries[:4], 10, data=ds.data)
-    np.testing.assert_array_equal(ids, ids_n)
-
-
 def test_buffered_reader_state_check():
     rows = np.arange(100, dtype=np.float32).reshape(100, 1)
     r = BufferedShardReader(rows, buffer_rows=10)
